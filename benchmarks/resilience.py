@@ -35,6 +35,11 @@ into a cheaper-but-honest answer instead of an error:
 Run as a section of the driver (emits BENCH_resilience.json):
 
     PYTHONPATH=src python -m benchmarks.run --only resilience
+
+This is a CPU-only tool: the sharded drill runs in a child process forced
+onto the CPU (``JAX_PLATFORMS=cpu`` with four host devices), because a
+child cannot take a chip its parent holds. Its times are CPU-sandbox
+numbers; the chip path is ``chip_smoke.py``.
 """
 
 from __future__ import annotations

@@ -23,8 +23,10 @@ IO-modeled host. Every engine row carries ``rounds`` (sequential beam
 rounds) and ``n_dist`` (full-LUT-equivalent distances per query) as parsed
 derived fields in BENCH_sharded.json.
 
-Run as a section of the driver (uses however many devices exist — 1 in the
-default CPU sandbox):
+This is a CPU-only tool: run as a script it forces four host CPU devices
+(``XLA_FLAGS``), and its times are CPU-sandbox numbers; the four-chip path
+is ``chip_smoke.py --four-chips``. Run as a section of the driver (uses
+however many devices exist — 1 in the default CPU sandbox):
 
     PYTHONPATH=src python -m benchmarks.run --only sharded
 

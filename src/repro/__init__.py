@@ -21,7 +21,3 @@ launch/    mesh / dryrun / train / serve drivers
 """
 
 __version__ = "1.0.0"
-
-from repro import _compat as _compat  # noqa: E402  (jax forward-compat shims)
-
-_compat.apply()

@@ -95,9 +95,11 @@ def _temp_scale(cfg: RPQConfig, d: jax.Array) -> jax.Array:
     return jax.lax.stop_gradient(jnp.mean(jnp.min(d, axis=-1)) + 1e-12)
 
 
-def soft_assign(cfg: RPQConfig, params: RPQParams, x: jax.Array) -> jax.Array:
-    """Eq. 6 (sign-fixed): codeword assignment probabilities (N, M, K)."""
-    d = subspace_distances(cfg, params, x)
+def soft_assign(cfg: RPQConfig, params: RPQParams, x: jax.Array,
+                *, backend: str = "auto") -> jax.Array:
+    """Eq. 6 (sign-fixed): codeword assignment probabilities (N, M, K).
+    Differentiable on every backend (kernels.ops.pq_pairwise)."""
+    d = subspace_distances(cfg, params, x, backend=backend)
     return jax.nn.softmax(-d / (_temp_scale(cfg, d) * cfg.assign_temp), axis=-1)
 
 

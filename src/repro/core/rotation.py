@@ -46,7 +46,7 @@ def rotation_from_params(theta: jax.Array, dim: int) -> jax.Array:
 
 def rotate(x: jax.Array, r: jax.Array) -> jax.Array:
     """Apply the rotation: x (.., D) → x @ R^T  (i.e. R x for row vectors)."""
-    return x @ r.T
+    return jnp.matmul(x, r.T, precision=jax.lax.Precision.HIGHEST)
 
 
 def split_subvectors(x: jax.Array, m: int) -> jax.Array:

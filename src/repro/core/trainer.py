@@ -26,7 +26,6 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
-from repro._compat import shard_map
 from repro.common import adam, one_cycle, clip_by_global_norm
 from repro.core import features as F
 from repro.core import losses as L
@@ -170,7 +169,7 @@ def make_dp_train_step(cfg: Q.RPQConfig, tcfg: TrainConfig, optimizer, mesh,
         return params, opt_state, comp_state, report, gnorm
 
     pb = P(dp)
-    step = shard_map(local_step, mesh=mesh,
+    step = jax.shard_map(local_step, mesh=mesh,
                      in_specs=(P(), P(), pb, P(), pb, pb, P()),
                      out_specs=(P(), P(), pb, P(), P()))
     return jax.jit(step)

@@ -12,6 +12,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+# Ground truth must be exact: a TPU's default f32 matmul runs bf16 passes,
+# whose error (~0.4% of ‖q‖·‖x‖) reorders neighbors closer than that.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @functools.partial(jax.jit, static_argnames=("k", "block", "exclude_self"))
 def knn_ids(x: jax.Array, q: jax.Array, k: int, *, block: int = 1024,
@@ -35,7 +39,8 @@ def knn_ids(x: jax.Array, q: jax.Array, k: int, *, block: int = 1024,
 
     def one(args):
         qi, off = args
-        d2 = jnp.sum(qi * qi, 1)[:, None] - 2.0 * qi @ x.T + x2[None, :]
+        d2 = (jnp.sum(qi * qi, 1)[:, None] + x2[None, :]
+              - 2.0 * jnp.matmul(qi, x.T, precision=HIGHEST))
         if exclude_self:
             rows = off + jnp.arange(block)
             d2 = jnp.where(jnp.arange(n)[None, :] == rows[:, None], jnp.inf, d2)

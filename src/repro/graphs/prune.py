@@ -67,9 +67,12 @@ def robust_prune(cand_ids: jax.Array, cand_dv: jax.Array, cand_pair: jax.Array,
     return jnp.take_along_axis(padded_ids, out, axis=1)
 
 
+@functools.partial(jax.jit, static_argnames=("r",))
 def prune_from_vectors(x: jax.Array, node_ids: jax.Array, cand_ids: jax.Array,
                        alpha: float, r: int, sentinel: int) -> jax.Array:
     """Convenience: gathers vectors and computes both distance tables.
+    Jitted, so the (B, C, C) pair table is reduced in one fusion instead of
+    materializing its (B, C, C, D) difference tensor.
 
     x must be sentinel-padded: x[(N+1), D] with x[N] finite (distances to the
     pad row are masked via the id check inside robust_prune).

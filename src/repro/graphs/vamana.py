@@ -9,6 +9,8 @@ property).
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -22,18 +24,19 @@ from repro.search.beam import beam_search, make_exact_dist_fn
 def build_vamana(key: jax.Array, x: jax.Array, *, r: int = 32, l: int = 64,
                  alpha: float = 1.2, passes: int = 2, batch: int = 1024,
                  verbose: bool = False) -> Graph:
-    """Build a Vamana PG over x (N, D). Returns a padded-adjacency Graph."""
+    """Build a Vamana PG over x (N, D). Returns a padded-adjacency Graph.
+
+    The (N, R) adjacency stays on the device across insert batches; only
+    the reverse-edge pass round-trips it through the host, once per pass.
+    """
     n, d = x.shape
     x = jnp.asarray(x, jnp.float32)
     xp = _pad_vectors(x)
-    dist_fn = make_exact_dist_fn(xp)
     medoid = find_medoid(x)
 
     key, kinit = jax.random.split(key)
-    nbrs = np.array(
-        jax.random.randint(kinit, (n, r), 0, n, jnp.int32))  # writable copy
-    self_loop = nbrs == np.arange(n)[:, None]
-    nbrs[self_loop] = (nbrs[self_loop] + 1) % n
+    nbrs = jax.random.randint(kinit, (n, r), 0, n, jnp.int32)
+    nbrs = jnp.where(nbrs == jnp.arange(n)[:, None], (nbrs + 1) % n, nbrs)
 
     n_pad = (-n) % batch
     for p in range(passes):
@@ -42,20 +45,30 @@ def build_vamana(key: jax.Array, x: jax.Array, *, r: int = 32, l: int = 64,
         order = np.asarray(jax.random.permutation(kperm, n))
         order = np.concatenate([order, order[: n_pad]])
         for s in range(0, len(order), batch):
-            ids = order[s:s + batch]
-            g = jnp.asarray(nbrs)
-            res = beam_search(g, medoid, x[ids], dist_fn, h=l, max_steps=4 * l)
-            cand = jnp.concatenate([res.ids, g[ids]], axis=1)       # (B, L+R)
-            cand = jnp.where(cand == jnp.asarray(ids)[:, None], n, cand)
-            pruned = prune_from_vectors(xp, jnp.asarray(ids), cand, a, r, n)
-            nbrs[ids] = np.asarray(pruned)
+            ids = jnp.asarray(order[s:s + batch])
+            cand = _batch_candidates(nbrs, xp, ids, medoid, l=l)  # (B, L+R)
+            pruned = prune_from_vectors(xp, ids, cand, a, r, n)
+            nbrs = nbrs.at[ids].set(pruned)
         # reverse-edge pass: j gains candidate i for every edge i→j
-        nbrs = _reverse_pass(xp, nbrs, a, r, batch)
+        nbrs = jnp.asarray(_reverse_pass(xp, np.array(nbrs), a, r, batch))
         if verbose:
-            deg = (nbrs < n).sum(1)
+            deg = np.asarray(jnp.sum(nbrs < n, axis=1))
             print(f"[vamana] pass {p}: mean degree {deg.mean():.1f}")
 
-    return Graph(neighbors=jnp.asarray(nbrs), medoid=medoid)
+    return Graph(neighbors=nbrs, medoid=medoid)
+
+
+@functools.partial(jax.jit, static_argnames=("l",))
+def _batch_candidates(nbrs: jax.Array, xp: jax.Array, ids: jax.Array,
+                      medoid: jax.Array, *, l: int) -> jax.Array:
+    """Insert candidates of one batch: its beam from the medoid plus its
+    current out-edges, self-ids masked to the sentinel. The vectors are an
+    argument here, not a constant folded into the compiled beam."""
+    n = nbrs.shape[0]
+    res = beam_search(nbrs, medoid, xp[ids], make_exact_dist_fn(xp), h=l,
+                      max_steps=4 * l)
+    cand = jnp.concatenate([res.ids, nbrs[ids]], axis=1)
+    return jnp.where(cand == ids[:, None], n, cand)
 
 
 def _reverse_pass(xp: jax.Array, nbrs: np.ndarray, alpha: float, r: int,

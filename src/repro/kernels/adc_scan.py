@@ -55,15 +55,8 @@ def _adc_scan_kernel(codes_ref, lut_ref, out_ref, *, m: int, k: int):
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def adc_scan(codes: jax.Array, lut: jax.Array, *, block_n: int = 1024,
-             interpret: bool | None = None) -> jax.Array:
-    """(N, M) int codes × (M, K) LUT → (N,) f32 distances. Pallas path.
-
-    ``interpret=None`` autodetects via kernels.ops.default_interpret
-    (compiled Mosaic on TPU, interpreter elsewhere).
-    """
-    if interpret is None:
-        from repro.kernels.ops import default_interpret
-        interpret = default_interpret()
+             interpret: bool = False) -> jax.Array:
+    """(N, M) int codes × (M, K) LUT → (N,) f32 distances. Pallas path."""
     n, m = codes.shape
     _, k = lut.shape
     n_pad = (-n) % block_n
@@ -108,14 +101,8 @@ def _adc_scan_batch_kernel(codes_ref, luts_ref, out_ref, *, m: int, k: int):
 @functools.partial(jax.jit, static_argnames=("block_n", "block_q", "interpret"))
 def adc_scan_batch(codes: jax.Array, luts: jax.Array, *, block_n: int = 256,
                    block_q: int = 128,
-                   interpret: bool | None = None) -> jax.Array:
-    """(N, M) codes × (Q, M, K) LUTs → (Q, N) f32 distances. Pallas path.
-
-    ``interpret=None`` autodetects via kernels.ops.default_interpret.
-    """
-    if interpret is None:
-        from repro.kernels.ops import default_interpret
-        interpret = default_interpret()
+                   interpret: bool = False) -> jax.Array:
+    """(N, M) codes × (Q, M, K) LUTs → (Q, N) f32 distances. Pallas path."""
     n, m = codes.shape
     q, _, k = luts.shape
     n_pad = (-n) % block_n

@@ -9,43 +9,31 @@ ids never leave SMEM, the gathered rows never leave VMEM.
 
 R′ is the FRONTIER width: the adjacency degree R classically, E·R under
 multi-expansion beam search (``search/beam.py`` with ``expand=E``,
-DESIGN.md §9). The kernel is width-agnostic; two knobs keep the wide rows
-efficient:
-
-* the per-row scalar gather loop is UNROLLED ×8 — each ``fori_loop`` trip
-  issues 8 independent row copies (SMEM id read + VMEM dynamic slice), so
-  the copies pipeline instead of serializing one loop trip per row (the
-  trip count at R′=256 drops 256 → 32);
-* ``block_q`` auto-tunes to the width (``_auto_block_q``): the query tile
-  shrinks 8 → 4 → 2 as R′ grows 64 → 128 → 256 so the LUT tile + out tile
-  + gather scratch VMEM working set stays roughly constant.
+DESIGN.md §9). ``block_q`` auto-tunes to the width (``_auto_block_q``): the
+query tile shrinks 8 → 4 → 2 as R′ grows 64 → 128 → 256.
 
 Layout (DESIGN.md §6, §9):
 
-* ``ids`` (Q, R′) int32 ride in as a scalar-prefetch argument — they live in
-  SMEM, where scalars are readable before/without a VMEM DMA, and drive the
-  row gather directly (the embedding-lookup idiom of
-  ``PrefetchScalarGridSpec``).
-* ``codes`` (N, M) int32 are block-resident in VMEM across all grid steps
-  (index_map pins block (0, 0)). N here is a SHARD's rows, not the corpus:
-  at 1M rows / 512 devices ≈ 2k rows × M=16 × 4 B ≈ 128 KiB — small next
-  to the LUT tile.
-* ``luts`` (bq, M, K) f32 tile per grid step; per query the reduce is the
-  same K-lane iota-compare as adc_scan's VPU formulation (M static unroll).
-* grid = (Q / bq,); per-(query, neighbor) row gathers are dynamic slices
-  into the resident codes block, staged through an (R′, M) VMEM scratch.
+* The code table stays in HBM (``memory_space=pl.ANY``); only the
+  frontier's rows are copied into VMEM. A TPU DMA moves whole 128-lane
+  rows of 32-bit words, so the table is viewed as WORD ROWS
+  (:func:`code_words`): each code row's bytes (u8 codes, or fs4 packed
+  nibbles) are little-endian packed into W = next_pow2(ceil(bytes/4))
+  int32 words, and 128 / W code rows share one 128-lane word row. This is
+  a reinterpretation of the uint8 bytes at rest, not a wider dtype: 1M ×
+  16-byte rows are 16 MB either way.
+* ``ids`` (Q, R′) int32 ride in per query tile as an SMEM block; each id
+  starts one async row copy (``pltpu.make_async_copy``) into an (R′, 128)
+  VMEM scratch, and records its lane offset inside the word row.
+* Per query the reduce extracts each needed word column with a masked lane
+  sum, shifts out each sub-code, and does the K-lane iota compare against
+  the query's LUT row (the VPU formulation of adc_scan; M static unroll).
+* grid = (Q / bq,); output tile (1, bq, R′) of a (Q / bq, bq, R′) array,
+  so any bq satisfies the TPU's (8, 128) block rule.
 
-VMEM @ bq=8, R′=64, M=16, K=256: LUT tile 8·16·256·4 = 128 KiB + codes +
-scratch ≪ 16 MB; @ bq=2, R′=256 the LUT tile is 32 KiB and the scratch
-16 KiB (budget table in DESIGN.md §9). Validated against ``ref.hop_adc_ref``
-in interpret mode by tests/test_kernels.py; ``ops.hop_adc`` dispatches
-Pallas-on-TPU / jnp-ref elsewhere.
-
-``hop_adc_fs`` below is the FAST-SCAN twin (DESIGN.md §8): the resident
-codes block holds 4-bit-packed bytes (half the bytes), the LUT tile is
-uint8 with a per-query affine (1/256th of the tile above — K drops to 16
-AND the entries to 1 byte), nibbles unpack in-register, and accumulation is
-exact int32; the dequant lives in ``ops.hop_adc_fs``.
+``hop_adc_fs`` is the FAST-SCAN twin (DESIGN.md §8): the same kernel over
+4-bit packed rows (half the bytes) with a K=16 integer LUT and exact int32
+accumulation; the dequant lives in ``ops.hop_adc_fs``.
 """
 
 from __future__ import annotations
@@ -57,10 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Rows gathered per fori_loop trip — 8 independent dynamic slices per trip
-# pipeline where a 1-row loop serialized (the ids wrapper pads R′ up to a
-# multiple of this; pad rows gather row 0 and are sliced off the output).
-GATHER_UNROLL = 8
+LANES = 128  # words per DMA row: one full lane row of 32-bit words
 
 
 def _auto_block_q(r: int) -> int:
@@ -71,191 +56,155 @@ def _auto_block_q(r: int) -> int:
     return max(1, 512 // max(r, 64))
 
 
-def _pad_ids_rows(ids_i: jax.Array) -> jax.Array:
-    """Pad the frontier axis to a GATHER_UNROLL multiple (pad lanes gather
-    row 0 — cheap, discarded by the caller's output slice)."""
-    r_pad = (-ids_i.shape[1]) % GATHER_UNROLL
-    if r_pad:
-        ids_i = jnp.pad(ids_i, ((0, 0), (0, r_pad)))
-    return ids_i
+def _row_words(row_bytes: int) -> int:
+    """int32 words per code row: a power of two, so rows tile a lane row."""
+    w = 1
+    while 4 * w < row_bytes:
+        w *= 2
+    assert w <= LANES, f"code rows of {row_bytes} bytes exceed one lane row"
+    return w
 
 
-def _gather_rows(ids_ref, codes_ref, gathered, q_abs, rp: int):
-    """Copy the rp neighbor code rows of query ``q_abs`` into scratch,
-    GATHER_UNROLL independent row copies per loop trip."""
-    def g_body(gi, _):
-        base = gi * GATHER_UNROLL
-        for j in range(GATHER_UNROLL):     # static unroll
-            row = ids_ref[q_abs, base + j]
-            gathered[pl.ds(base + j, 1), :] = codes_ref[pl.ds(row, 1), :]
-        return _
+def code_words(codes: jax.Array) -> jax.Array:
+    """(N, B) uint8 code rows → (ceil(N·W/128), 128) int32 word rows.
 
-    jax.lax.fori_loop(0, rp // GATHER_UNROLL, g_body, 0)
+    Code row r occupies words ``[(r % (128/W))·W, +W)`` of word row
+    ``r // (128/W)``; byte b of the row sits in word b // 4 at bits
+    8·(b % 4), so sub-code j of a row with ``bits``-wide codes sits at bit
+    offset j·bits of the row (pq.pack's nibble convention included)."""
+    n, b = codes.shape
+    w = _row_words(b)
+    c = jnp.pad(codes.astype(jnp.uint32),
+                ((0, (-n) % (LANES // w)), (0, 4 * w - b))).reshape(-1, w, 4)
+    words = c[..., 0] | (c[..., 1] << 8) | (c[..., 2] << 16) | (c[..., 3] << 24)
+    return jax.lax.bitcast_convert_type(words, jnp.int32).reshape(-1, LANES)
 
 
-def _hop_adc_kernel(ids_ref, codes_ref, luts_ref, out_ref, gathered,
-                    *, m: int, m_eff: int, k: int, rp: int, block_q: int):
-    """One grid step: block_q queries × R′ fused gather-reduce. ``m_eff ≤ m``
+def _hop_kernel(ids_ref, words_hbm, luts_ref, out_ref, buf, lane0, sem, *,
+                bits: int, m_eff: int, k: int, w: int, rp: int,
+                block_q: int):
+    """One grid step: block_q queries × R′ fused gather-reduce. ``m_eff ≤ M``
     statically shortens the reduce unroll — the partial-LUT lower-bound pass
-    of hop pruning (DESIGN.md §11); the resident codes block stays full-width
-    (no HBM reslice per call), only the loop trip count shrinks."""
-    q0 = pl.program_id(0) * block_q
+    of hop pruning (DESIGN.md §11)."""
+    rows_per_word_row = LANES // w
+    code_mask = (1 << bits) - 1
 
-    def q_body(qi, _):
-        # 1. gather this query's R′ neighbor code rows into VMEM scratch;
-        #    the row index comes straight from SMEM (no VMEM round-trip).
-        _gather_rows(ids_ref, codes_ref, gathered, q0 + qi, rp)
-        rows = gathered[...]                               # (R′, M) int32
-        lut = luts_ref[pl.ds(qi, 1)][0]                    # (M, K) f32
-        # 2. LUT reduce: K-lane iota compare per subspace (VPU formulation)
+    def row_copy(src_row, dst_row):
+        return pltpu.make_async_copy(words_hbm.at[pl.ds(src_row, 1)],
+                                     buf.at[pl.ds(dst_row, 1)], sem)
+
+    def q_body(qi, carry):
+        # 1. one row copy per neighbor id, ids read straight from SMEM
+        def issue(j, c):
+            row = ids_ref[0, qi, j]
+            row_copy(row // rows_per_word_row, j).start()
+            lane0[pl.ds(j, 1), :] = jnp.full(
+                (1, LANES), (row % rows_per_word_row) * w, jnp.int32)
+            return c
+
+        jax.lax.fori_loop(0, rp, issue, 0)
+
+        def wait(j, c):
+            row_copy(0, 0).wait()
+            return c
+
+        jax.lax.fori_loop(0, rp, wait, 0)
+        # 2. word columns of this query's rows: masked lane sums (R′, 1)
+        rel = jax.lax.broadcasted_iota(jnp.int32, (rp, LANES), 1) - lane0[...]
+        words = buf[...]
+        cols = {}
+
+        def word_col(i):
+            if i not in cols:
+                cols[i] = jnp.sum(jnp.where(rel == i, words, 0), axis=1,
+                                  keepdims=True)
+            return cols[i]
+
+        # 3. LUT reduce: K-lane iota compare per subspace (VPU formulation)
+        lut = luts_ref[pl.ds(qi, 1)][0]                    # (M, K)
         iota = jax.lax.broadcasted_iota(jnp.int32, (rp, k), 1)
-        acc = jnp.zeros((rp,), jnp.float32)
+        acc = jnp.zeros((rp,), lut.dtype)
         for j in range(m_eff):                             # M static unroll
-            mask = rows[:, j:j + 1] == iota                # (R′, K)
+            bit = j * bits
+            code = jax.lax.shift_right_logical(
+                word_col(bit // 32), bit % 32) & code_mask  # (R′, 1)
             acc = acc + jnp.sum(
-                jnp.where(mask, lut[j, :][None, :], 0.0), axis=1)
-        out_ref[pl.ds(qi, 1), :] = acc[None]
-        return _
+                jnp.where(code == iota, lut[j, :][None, :], 0), axis=1)
+        out_ref[0, pl.ds(qi, 1), :] = acc[None]
+        return carry
 
     jax.lax.fori_loop(0, block_q, q_body, 0)
+
+
+def _hop_call(words, ids, luts, *, bits: int, row_bytes: int, m_eff: int,
+              block_q: int | None, interpret: bool):
+    q, r = ids.shape
+    _, m, k = luts.shape
+    w = _row_words(row_bytes)
+    block_q = min(block_q or _auto_block_q(r), q)
+    q_pad = (-q) % block_q
+    ids_i = ids.astype(jnp.int32)
+    if q_pad:  # padded queries gather row 0 — cheap, discarded below
+        ids_i = jnp.pad(ids_i, ((0, q_pad), (0, 0)))
+        luts = jnp.pad(luts, ((0, q_pad), (0, 0), (0, 0)))
+    g = ids_i.shape[0] // block_q
+    out = pl.pallas_call(
+        functools.partial(_hop_kernel, bits=bits, m_eff=m_eff, k=k, w=w,
+                          rp=r, block_q=block_q),
+        grid=(g,),
+        in_specs=[
+            pl.BlockSpec((1, block_q, r), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),              # HBM-resident
+            pl.BlockSpec((block_q, m, k), lambda i: (i, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, r), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((g, block_q, r), luts.dtype),
+        scratch_shapes=[pltpu.VMEM((r, LANES), jnp.int32),
+                        pltpu.VMEM((r, LANES), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
+        interpret=interpret,
+    )(ids_i.reshape(g, block_q, r), words, luts)
+    return out.reshape(g * block_q, r)[:q]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_q", "interpret", "m_prefix"))
 def hop_adc(codes: jax.Array, ids: jax.Array, luts: jax.Array, *,
-            block_q: int | None = None,
-            interpret: bool | None = None,
+            block_q: int | None = None, interpret: bool = False,
             m_prefix: int = 0) -> jax.Array:
-    """Fused per-hop ADC: (N, M) codes, (Q, R′) ids, (Q, M, K) LUTs → (Q, R′).
+    """Fused per-hop ADC: (N, M) uint8 codes, (Q, R′) ids, (Q, M, K ≤ 256)
+    f32 LUTs → (Q, R′) f32.
 
     ``out[q, i] = sum_j luts[q, j, codes[ids[q, i], j]]`` — the distance of
     query q to its i-th candidate neighbor. All ids must be valid rows in
     ``[0, N)`` (the beam passes masked-to-0 ids for dead lanes and infs the
-    distances afterwards). Codes/ids arrive int32, LUTs f32 — the ONE cast
-    from caller dtypes (uint8 codes etc.) lives in kernels.ops, the
-    dispatch boundary. ``block_q=None`` auto-tunes the query tile to the
-    frontier width (``_auto_block_q``); ``interpret=None`` autodetects:
-    compiled Pallas on TPU, interpreter elsewhere
-    (kernels.ops.default_interpret). ``0 < m_prefix < M`` reduces only the
-    first m_prefix subspaces — the hop-pruning lower bound (the grid, specs
-    and resident codes are unchanged; only the reduce unroll shortens).
+    distances afterwards). ``block_q=None`` auto-tunes the query tile to the
+    frontier width (``_auto_block_q``). ``0 < m_prefix < M`` reduces only
+    the first m_prefix subspaces — the hop-pruning lower bound.
     """
-    if interpret is None:
-        from repro.kernels.ops import default_interpret
-        interpret = default_interpret()
-    q, r = ids.shape
-    n, m = codes.shape
-    _, _, k = luts.shape
-    if block_q is None:
-        block_q = _auto_block_q(r)
-    q_pad = (-q) % block_q
-    ids_i = _pad_ids_rows(ids.astype(jnp.int32))
-    rp = ids_i.shape[1]
-    luts_f = luts.astype(jnp.float32)
-    if q_pad:  # padded queries gather row 0 — cheap, discarded below
-        ids_i = jnp.pad(ids_i, ((0, q_pad), (0, 0)))
-        luts_f = jnp.pad(luts_f, ((0, q_pad), (0, 0), (0, 0)))
-    qp = ids_i.shape[0]
-    m_eff = m_prefix if 0 < m_prefix < m else m
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(qp // block_q,),
-        in_specs=[
-            pl.BlockSpec((n, m), lambda i, ids: (0, 0)),        # resident
-            pl.BlockSpec((block_q, m, k), lambda i, ids: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_q, rp), lambda i, ids: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((rp, m), jnp.int32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_hop_adc_kernel, m=m, m_eff=m_eff, k=k, rp=rp,
-                          block_q=block_q),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((qp, rp), jnp.float32),
-        interpret=interpret,
-    )(ids_i, codes.astype(jnp.int32), luts_f)
-    return out[:q, :r]
-
-
-# --------------------------------------------------------------------------
-# Fast-scan variant: 4-bit packed codes + uint8 LUTs (DESIGN.md §8)
-# --------------------------------------------------------------------------
-
-def _hop_adc_fs_kernel(ids_ref, codes_ref, luts_ref, out_ref, gathered,
-                       *, m: int, m_eff: int, mb: int, rp: int, block_q: int):
-    """Packed twin of ``_hop_adc_kernel``: the resident codes block and the
-    gather scratch hold PACKED bytes (half the VMEM), the LUT tile is uint8
-    (a quarter), nibbles unpack in-register, and the reduce accumulates
-    int32 — dequantization happens once in the wrapper. ``m_eff ≤ m``
-    statically shortens the reduce unroll (hop-pruning lower bound)."""
-    q0 = pl.program_id(0) * block_q
-
-    def q_body(qi, _):
-        _gather_rows(ids_ref, codes_ref, gathered, q0 + qi, rp)
-        p = gathered[...].astype(jnp.int32)                # (R′, Mb) packed
-        nib = jnp.stack([p & 0xF, (p >> 4) & 0xF], axis=-1)
-        rows = nib.reshape(rp, 2 * mb)[:, :m]              # (R′, M)
-        lut = luts_ref[pl.ds(qi, 1)][0].astype(jnp.int32)  # (M, 16)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (rp, 16), 1)
-        acc = jnp.zeros((rp,), jnp.int32)
-        for j in range(m_eff):                             # M static unroll
-            mask = rows[:, j:j + 1] == iota                # (R′, 16)
-            acc = acc + jnp.sum(jnp.where(mask, lut[j, :][None, :], 0),
-                                axis=1)
-        out_ref[pl.ds(qi, 1), :] = acc[None]
-        return _
-
-    jax.lax.fori_loop(0, block_q, q_body, 0)
+    m = codes.shape[1]
+    return _hop_call(code_words(codes), ids, luts.astype(jnp.float32),
+                     bits=8, row_bytes=m,
+                     m_eff=m_prefix if 0 < m_prefix < m else m,
+                     block_q=block_q, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "block_q", "interpret",
                                              "m_prefix"))
 def hop_adc_fs(packed: jax.Array, ids: jax.Array, luts_u8: jax.Array, *,
-               m: int, block_q: int | None = None,
-               interpret: bool | None = None,
+               m: int, block_q: int | None = None, interpret: bool = False,
                m_prefix: int = 0) -> jax.Array:
     """Fused per-hop fast-scan ADC: (N, ceil(M/2)) packed codes, (Q, R′)
     ids, (Q, M, 16) u8 LUTs → (Q, R′) int32 exact accumulators.
 
     Pure-integer on purpose — the per-query dequant affine is applied by
     ``ops.hop_adc_fs`` so the float op sequence matches the oracle
-    ``ref.hop_adc_fs_ref`` exactly on every backend. Canonical dtypes
-    (uint8 packed, int32 ids) are enforced by kernels.ops. ``block_q=None``
-    auto-tunes the query tile to the frontier width. ``0 < m_prefix < m``
+    ``ref.hop_adc_fs_ref`` exactly on every backend. ``0 < m_prefix < m``
     accumulates only the first m_prefix subspaces (hop-pruning lower
     bound); the caller's dequant must then use ``m_prefix · bias``.
     """
-    if interpret is None:
-        from repro.kernels.ops import default_interpret
-        interpret = default_interpret()
-    q, r = ids.shape
-    n, mb = packed.shape
-    if block_q is None:
-        block_q = _auto_block_q(r)
-    q_pad = (-q) % block_q
-    ids_i = _pad_ids_rows(ids.astype(jnp.int32))
-    rp = ids_i.shape[1]
-    luts_q = luts_u8
-    if q_pad:  # padded queries gather row 0 — cheap, discarded below
-        ids_i = jnp.pad(ids_i, ((0, q_pad), (0, 0)))
-        luts_q = jnp.pad(luts_q, ((0, q_pad), (0, 0), (0, 0)))
-    qp = ids_i.shape[0]
-    m_eff = m_prefix if 0 < m_prefix < m else m
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(qp // block_q,),
-        in_specs=[
-            pl.BlockSpec((n, mb), lambda i, ids: (0, 0)),       # resident
-            pl.BlockSpec((block_q, m, 16), lambda i, ids: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_q, rp), lambda i, ids: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((rp, mb), jnp.uint8)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_hop_adc_fs_kernel, m=m, m_eff=m_eff, mb=mb, rp=rp,
-                          block_q=block_q),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((qp, rp), jnp.int32),
-        interpret=interpret,
-    )(ids_i, packed, luts_q)
-    return out[:q, :r]
+    return _hop_call(code_words(packed), ids, luts_u8.astype(jnp.int32),
+                     bits=4, row_bytes=packed.shape[1],
+                     m_eff=m_prefix if 0 < m_prefix < m else m,
+                     block_q=block_q, interpret=interpret)

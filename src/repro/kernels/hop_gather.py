@@ -35,14 +35,8 @@ def _hop_gather_kernel(codes_ref, luts_ref, out_ref, *, m: int, k: int):
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
 def hop_gather(codes: jax.Array, luts: jax.Array, *, block_q: int = 8,
-               interpret: bool | None = None) -> jax.Array:
-    """(Q, R, M) int codes × (Q, M, K) LUTs → (Q, R) f32 distances.
-
-    ``interpret=None`` autodetects via kernels.ops.default_interpret.
-    """
-    if interpret is None:
-        from repro.kernels.ops import default_interpret
-        interpret = default_interpret()
+               interpret: bool = False) -> jax.Array:
+    """(Q, R, M) int codes × (Q, M, K) LUTs → (Q, R) f32 distances."""
     q, r, m = codes.shape
     _, _, k = luts.shape
     q_pad = (-q) % block_q

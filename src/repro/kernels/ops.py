@@ -1,24 +1,24 @@
 """Public, jitted entry points for the PQ kernels with backend dispatch.
 
 Call these from library code. On TPU they run the compiled Pallas kernels;
-on CPU (this container) they run the pure-jnp oracle, which XLA fuses well
-— the Pallas path is still exercised on CPU via interpret mode in the tests
-and can be forced with ``backend="interpret"``.
+elsewhere they run the pure-jnp oracle, which XLA fuses well — the Pallas
+path is still exercised on CPU via interpret mode in the tests.
 
 Backends:
 
-* ``"auto"``      — Pallas compiled on TPU, jnp oracle elsewhere (default).
-* ``"pallas"``    — force the Pallas path; interpret mode is then decided
-                    by :func:`default_interpret` (compiled only on TPU), so
-                    forcing pallas on CPU runs the interpreter, not a crash.
-* ``"interpret"`` — force the Pallas path in interpreter mode (tests).
-* ``"ref"``       — force the pure-jnp oracle from :mod:`repro.kernels.ref`.
+* ``"auto"``      — compiled Pallas on TPU, jnp oracle elsewhere (default).
+* ``"pallas"``    — the compiled Pallas kernel; raises off-TPU, so a run
+                    that asked for the device kernel never silently gets
+                    the interpreter instead.
+* ``"interpret"`` — the Pallas kernel in interpreter mode (CPU tests).
+* ``"ref"``       — the pure-jnp oracle from :mod:`repro.kernels.ref`.
 
 Dtype boundary: callers hand in codes in whatever integer dtype they store
 (uint8 for K ≤ 256 indices, uint8 packed bytes for the fs4 layout, int32
 ids) and THIS module casts once to the canonical kernel dtypes — int32
-plain codes/ids, uint8 packed codes, f32 LUTs. Kernel modules and oracles
-assume the canonical dtypes; no per-call casting in callers.
+codes/ids for the scans, uint8 code rows for the hop gathers, f32 LUTs.
+Kernel modules and oracles assume the canonical dtypes; no per-call casting
+in callers.
 """
 
 from __future__ import annotations
@@ -95,27 +95,30 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def default_interpret() -> bool:
-    """The ONE backend-autodetect switch for Pallas interpret mode.
-
-    Compiled Mosaic kernels exist only on TPU; everywhere else (CPU CI,
-    laptops) the Pallas interpreter is the correct default. Kernel modules
-    resolve ``interpret=None`` through this helper instead of hardcoding
-    ``interpret=True`` (which would silently interpret on real TPUs too —
-    the bug this replaces; see DESIGN.md §3).
-    """
-    return not _on_tpu()
-
-
 def _resolve(backend: Backend) -> str:
     if backend == "auto":
         return "pallas" if _on_tpu() else "ref"
     return backend
 
 
-def _interpret_flag(mode: str) -> bool:
-    """interpret= for a resolved pallas/interpret mode."""
-    return True if mode == "interpret" else default_interpret()
+# Names of the Pallas kernels this process has traced into a program
+# (compiled or interpreted) — chip_smoke.py prints which ones ran.
+TRACED_KERNELS: set[str] = set()
+
+
+def _interpret_flag(mode: str, kernel: str) -> bool:
+    """interpret= for a resolved pallas/interpret mode. Only ``"interpret"``
+    interprets: ``"pallas"`` off-TPU raises instead of quietly running the
+    interpreter in place of the device kernel."""
+    TRACED_KERNELS.add(kernel)
+    if mode == "interpret":
+        return True
+    if not _on_tpu():
+        raise RuntimeError(
+            f"backend='pallas' needs a TPU (JAX default backend is "
+            f"{jax.default_backend()!r}); use backend='interpret' for the "
+            f"Pallas interpreter or 'ref' for the jnp oracle")
+    return False
 
 
 def adc_scan(codes, lut, *, backend: Backend = "auto", block_n: int = 1024):
@@ -125,7 +128,7 @@ def adc_scan(codes, lut, *, backend: Backend = "auto", block_n: int = 1024):
     if mode == "ref":
         return _ref.adc_scan_ref(codes, lut)
     return _adc.adc_scan(codes, lut, block_n=block_n,
-                         interpret=_interpret_flag(mode))
+                         interpret=_interpret_flag(mode, "adc_scan"))
 
 
 def adc_scan_batch(codes, luts, *, backend: Backend = "auto",
@@ -136,7 +139,8 @@ def adc_scan_batch(codes, luts, *, backend: Backend = "auto",
     if mode == "ref":
         return _ref.adc_scan_batch_ref(codes, luts)
     return _adc.adc_scan_batch(codes, luts, block_n=block_n, block_q=block_q,
-                               interpret=_interpret_flag(mode))
+                               interpret=_interpret_flag(mode,
+                                                         "adc_scan_batch"))
 
 
 def adc_scan_fs(packed, luts_u8, scale, bias, *, backend: Backend = "auto",
@@ -156,7 +160,7 @@ def adc_scan_fs(packed, luts_u8, scale, bias, *, backend: Backend = "auto",
         return _ref.adc_scan_fs_ref(packed, luts_u8, scale, bias)
     acc = _adcfs.adc_scan_fs(packed, luts_u8, block_n=block_n,
                              block_q=block_q,
-                             interpret=_interpret_flag(mode))
+                             interpret=_interpret_flag(mode, "adc_scan_fs"))
     return _dequant(acc, scale, bias, luts_u8.shape[1])
 
 
@@ -169,7 +173,15 @@ def hop_gather(codes, luts, *, backend: Backend = "auto", block_q: int = 8):
     if mode == "ref":
         return _ref.hop_gather_ref(codes, luts)
     return _hopg.hop_gather(codes, luts, block_q=block_q,
-                            interpret=_interpret_flag(mode))
+                            interpret=_interpret_flag(mode, "hop_gather"))
+
+
+def _hop_codes(codes, k: int) -> jax.Array:
+    """Code rows for the fused hop kernels: uint8 bytes at rest (K ≤ 256)."""
+    if k > 256:
+        raise ValueError(f"the fused hop kernels gather uint8 code rows; "
+                         f"K={k} > 256 needs backend='ref'")
+    return _codes_u8(codes)
 
 
 def hop_adc(codes, ids, luts, *, backend: Backend = "auto",
@@ -185,18 +197,20 @@ def hop_adc(codes, ids, luts, *, backend: Backend = "auto",
     ``0 < m_prefix < M`` reduces only the FIRST m_prefix subspaces — the
     partial-LUT lower bound of hop pruning (DESIGN.md §11; every LUT entry
     is a squared subdistance ≥ 0, so the prefix sum bounds the full sum
-    from below). The Pallas path keeps the resident codes block full-width
-    and statically shortens the reduce unroll; the oracle slices."""
+    from below). The Pallas path statically shortens the reduce unroll; the
+    oracle slices."""
     mode = _resolve(backend)
-    codes = _codes_i32(codes)
     ids = _codes_i32(ids)
     mp = m_prefix if 0 < m_prefix < codes.shape[1] else 0
     if mode == "ref":
+        codes = _codes_i32(codes)
         if mp:
             return _ref.hop_adc_ref(codes[:, :mp], ids, luts[:, :mp])
         return _ref.hop_adc_ref(codes, ids, luts)
-    return _hop.hop_adc(codes, ids, luts, block_q=block_q,
-                        interpret=_interpret_flag(mode), m_prefix=mp)
+    return _hop.hop_adc(_hop_codes(codes, luts.shape[2]), ids, luts,
+                        block_q=block_q,
+                        interpret=_interpret_flag(mode, "hop_adc"),
+                        m_prefix=mp)
 
 
 def hop_adc_fs(packed, ids, luts_u8, scale, bias, *,
@@ -204,8 +218,8 @@ def hop_adc_fs(packed, ids, luts_u8, scale, bias, *,
                m_prefix: int = 0):
     """FUSED per-hop FAST-SCAN ADC: (N, ceil(M/2)) packed codes, (Q, R′)
     ids, (Q, M, 16) uint8 LUTs + (Q,) (scale, bias) → (Q, R′) f32 — the
-    packed twin of :func:`hop_adc` (same gather fusion, half the resident
-    code bytes, quarter LUT bytes, int32 accumulation, same frontier-width
+    packed twin of :func:`hop_adc` (same gather fusion, half the code
+    bytes, quarter LUT bytes, int32 accumulation, same frontier-width
     auto-tuning at ``block_q=None``).
 
     ``m_prefix`` as in :func:`hop_adc`; the dequant then uses
@@ -225,17 +239,47 @@ def hop_adc_fs(packed, ids, luts_u8, scale, bias, *,
                                        luts_u8[:, :mp], scale, bias)
         return _ref.hop_adc_fs_ref(packed, ids, luts_u8, scale, bias)
     acc = _hop.hop_adc_fs(packed, ids, luts_u8, m=m, block_q=block_q,
-                          interpret=_interpret_flag(mode), m_prefix=mp)
+                          interpret=_interpret_flag(mode, "hop_adc_fs"),
+                          m_prefix=mp)
     return _dequant(acc, scale, bias, mp or m)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _pq_pairwise_kernel(x, codebook, block_n: int, interpret: bool):
+    return _pqp.pq_pairwise(x, codebook, block_n=block_n, interpret=interpret)
+
+
+def _pq_pairwise_fwd(x, codebook, block_n, interpret):
+    return _pq_pairwise_kernel(x, codebook, block_n, interpret), (x, codebook)
+
+
+def _pq_pairwise_bwd(block_n, interpret, res, g):
+    """Closed-form gradient of d = ‖x‖² − 2x·c + ‖c‖² (a pallas_call has no
+    reverse-mode rule of its own): ∂/∂x = 2(x·Σ_k g − g·c),
+    ∂/∂c = 2(c·Σ_n g − gᵀ·x), per subspace."""
+    x, c = res
+    xf, cf = x.astype(jnp.float32), c.astype(jnp.float32)
+    dx = 2.0 * (xf * jnp.sum(g, axis=2)[..., None]
+                - jnp.einsum("nmk,mkd->nmd", g, cf))
+    dc = 2.0 * (cf * jnp.sum(g, axis=0)[..., None]
+                - jnp.einsum("nmk,nmd->mkd", g, xf))
+    return dx.astype(x.dtype), dc.astype(c.dtype)
+
+
+_pq_pairwise_kernel.defvjp(_pq_pairwise_fwd, _pq_pairwise_bwd)
+
+
 def pq_pairwise(x, codebook, *, backend: Backend = "auto", block_n: int = 512):
-    """Sub-vector/codeword distance table: (N,M,dsub) × (M,K,dsub) → (N,M,K)."""
+    """Sub-vector/codeword distance table: (N,M,dsub) × (M,K,dsub) → (N,M,K).
+
+    Differentiable on every backend: the Pallas path carries the closed-form
+    backward pass (``_pq_pairwise_bwd``), so the RPQ losses train through
+    the kernel on TPU."""
     mode = _resolve(backend)
     if mode == "ref":
         return _ref.pq_pairwise_ref(x, codebook)
-    return _pqp.pq_pairwise(x, codebook, block_n=block_n,
-                            interpret=_interpret_flag(mode))
+    return _pq_pairwise_kernel(x, codebook, block_n,
+                               _interpret_flag(mode, "pq_pairwise"))
 
 
 def kmeans_assign(x, centroids, *, backend: Backend = "auto"):

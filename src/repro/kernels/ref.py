@@ -159,7 +159,8 @@ def pq_pairwise_ref(x: jax.Array, codebook: jax.Array) -> jax.Array:
     c = codebook.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=-1)[:, :, None]           # (N, M, 1)
     c2 = jnp.sum(c * c, axis=-1)[None, :, :]           # (1, M, K)
-    xc = jnp.einsum("nmd,mkd->nmk", x, c)              # (N, M, K)
+    xc = jnp.einsum("nmd,mkd->nmk", x, c,              # (N, M, K)
+                    precision=jax.lax.Precision.HIGHEST)
     return x2 - 2.0 * xc + c2
 
 

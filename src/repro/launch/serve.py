@@ -22,7 +22,9 @@ Loads the latest checkpoint written by launch/train.py, rebuilds the
 serving engine (codes are re-encoded from the checkpointed quantizer —
 deterministic), and either runs a one-shot evaluation batch or reads
 newline-delimited query vectors from stdin (toy request loop; a real
-deployment fronts this with an RPC layer).
+deployment fronts this with an RPC layer). In-process callers build the
+arguments with :func:`parser` and call :func:`run`, which returns what it
+measured.
 
 Scenarios (search/engine.py, DESIGN.md §5–§6):
 
@@ -90,6 +92,7 @@ from repro.dist.fault import ChaosPlan, InjectedFailure
 from repro.dist.retry import RetryPolicy
 from repro.graphs.knn import knn_ids
 from repro.graphs.partition import PartitionedGraph, build_partitioned_vamana
+from repro.launch import compile_cache
 from repro.launch.train import build_or_load_graph
 from repro.pq import base as pqbase
 from repro.pq import pack
@@ -102,10 +105,11 @@ from repro.search.metrics import live_ground_truth, measure_qps, recall_at_k
 def build_or_load_partitioned_graph(key, x, cache_path: str, n_shards: int,
                                     r: int, l: int) -> PartitionedGraph:
     """Per-shard Vamana subgraphs, cached next to the checkpoint (the
-    partition depends on the shard count, so the cache is keyed by it)."""
+    partition depends on the shard count and the row count, so a cache
+    built for other values is rebuilt)."""
     if cache_path and os.path.exists(cache_path):
         z = np.load(cache_path)
-        if int(z["n_shards"]) == n_shards:
+        if int(z["n_shards"]) == n_shards and int(z["n"]) == x.shape[0]:
             return PartitionedGraph(neighbors=jnp.asarray(z["neighbors"]),
                                     medoids=jnp.asarray(z["medoids"]),
                                     n=int(z["n"]))
@@ -279,7 +283,8 @@ def run_streaming(args, model, ds, plan: Optional[ChaosPlan] = None) -> None:
     evaluate("consolidated")
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
+    """The command line of ``python -m repro.launch.serve``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--ckpt-dir", required=True)
     ap.add_argument("--dataset", default="sift-small")
@@ -356,8 +361,20 @@ def main():
                     "concurrent pread workers")
     ap.add_argument("--port-stdin", action="store_true",
                     help="read whitespace-separated query vectors on stdin")
-    args = ap.parse_args()
+    return ap
 
+
+def main():
+    args = parser().parse_args()
+    compile_cache.enable()
+    return run(args)
+
+
+def run(args) -> dict:
+    """Serve one scenario as :func:`main` parses it; returns what it measured
+    (``recall``, ``qps``, the ``engine`` and its ``result``, the
+    ``queries`` and exact ``gt`` ids) so in-process callers can check it.
+    The streaming and stdin-port scenarios print only and return ``{}``."""
     plan = ChaosPlan.parse(args.chaos) if args.chaos else None
     retry = None
     if plan is not None and plan.io_fault_p > 0:
@@ -371,7 +388,8 @@ def main():
 
     state = ckpt.restore(args.ckpt_dir, retry=retry)
     extra = state.get("extra") or {}
-    ds = load_dataset(extra.get("dataset", args.dataset))
+    ds = load_dataset(extra.get("dataset", args.dataset),
+                      scale=extra.get("scale"))
     m, k = extra.get("m", 8), extra.get("k", 64)
     cfg = RPQConfig(dim=ds.dim, m=m, k=k)
     flat = state["params"]
@@ -393,7 +411,7 @@ def main():
                 "--port-stdin is not available with --scenario streaming: "
                 "the scenario runs a fixed churn loop, not a query port")
         run_streaming(args, model, ds, plan)
-        return
+        return {}
 
     codes = pqbase.encode(model, ds.base)
     if args.codes == "fs4":
@@ -483,7 +501,7 @@ def main():
             ids = np.asarray(res.ids[0]).tolist()
             print(f"ids={ids} dists={np.asarray(res.dists[0]).round(3).tolist()} "
                   f"({dt:.1f} ms, {int(res.hops[0])} hops)")
-        return
+        return {}
 
     policy = DegradationPolicy()
     skw = policy.apply(engine, args.degrade_level, h=args.h,
@@ -517,8 +535,9 @@ def main():
     trunc = (f"truncated={float(np.asarray(res.truncated).mean()):.2f} "
              if res.truncated is not None else "")
     degr = "DEGRADED " if res.degraded else ""
+    recall = recall_at_k(res.ids, gt, args.k)
     print(f"[serve] {args.scenario}: recall@{args.k}="
-          f"{recall_at_k(res.ids, gt, args.k):.4f} qps={qps:.1f} "
+          f"{recall:.4f} qps={qps:.1f} "
           f"hops={float(res.hops.mean()):.1f} {rounds}{trunc}{degr}"
           f"resident={engine.memory_bytes()/1e6:.1f}MB")
     if args.scenario == "disk":
@@ -527,6 +546,8 @@ def main():
               f"bytes_read={io['bytes_read']} n_reads={io['n_reads']} "
               f"io_wait={io['io_wait_s']*1e3:.1f}ms "
               f"retries={io['n_retries']}")
+    return {"recall": recall, "qps": qps, "engine": engine, "result": res,
+            "search_kwargs": skw, "queries": ds.queries, "gt": gt}
 
 
 if __name__ == "__main__":

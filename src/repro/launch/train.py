@@ -8,6 +8,8 @@ Builds (or loads) the dataset + Vamana PG, then runs the multi-feature
 joint training with atomic checkpointing; on restart (--resume or the
 supervise() wrapper after an injected failure) it continues from the
 latest checkpoint — the restart is bit-identical (tests/test_dist.py).
+In-process callers build the arguments with :func:`parser` and call
+:func:`run`.
 """
 
 from __future__ import annotations
@@ -25,6 +27,7 @@ from repro.data import load_dataset
 from repro.dist import checkpoint as ckpt
 from repro.dist.fault import FailureInjector, supervise
 from repro.graphs import build_vamana
+from repro.launch import compile_cache
 from repro.pq import base as pqbase
 from repro.search.engine import HybridEngine
 from repro.search.metrics import recall_at_k
@@ -32,11 +35,14 @@ from repro.graphs.knn import knn_ids
 
 
 def build_or_load_graph(key, x, cache_path: str, r: int, l: int):
+    """Vamana graph over x, cached at ``cache_path``. A cached graph over a
+    different row count or degree is rebuilt, never reused."""
     if cache_path and os.path.exists(cache_path):
         z = np.load(cache_path)
-        from repro.graphs.adjacency import Graph
-        return Graph(neighbors=jnp.asarray(z["neighbors"]),
-                     medoid=jnp.asarray(z["medoid"]))
+        if z["neighbors"].shape == (x.shape[0], r):
+            from repro.graphs.adjacency import Graph
+            return Graph(neighbors=jnp.asarray(z["neighbors"]),
+                         medoid=jnp.asarray(z["medoid"]))
     g = build_vamana(key, x, r=r, l=l)
     if cache_path:
         os.makedirs(os.path.dirname(cache_path) or ".", exist_ok=True)
@@ -84,15 +90,16 @@ def run(args) -> dict:
         injector.maybe_fail(step)
         if step % args.checkpoint_every == 0 and step > 0:
             ckpt.save(args.ckpt_dir, step, keep=args.keep, params=p, opt=o,
-                      extra={"dataset": args.dataset, "m": args.m, "k": args.k})
+                      extra={"dataset": args.dataset, "scale": args.scale,
+                             "m": args.m, "k": args.k})
 
     state = T.fit(kt, cfg, tcfg, x, graph, params=params,
                   opt_state=opt_state, start_step=start_step,
                   checkpoint_cb=checkpoint_cb, verbose=not args.quiet)
     ckpt.save(args.ckpt_dir, tcfg.steps, keep=args.keep, params=state.params,
               opt=state.opt_state,
-              extra={"final": True, "dataset": args.dataset, "m": args.m,
-                     "k": args.k})
+              extra={"final": True, "dataset": args.dataset,
+                     "scale": args.scale, "m": args.m, "k": args.k})
 
     # final evaluation: hybrid (DiskANN) serving on the base set
     model = T.to_model(cfg, state.params)
@@ -110,7 +117,8 @@ def run(args) -> dict:
     return {"recall": rec, "history": state.history}
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
+    """The command line of ``python -m repro.launch.train``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="sift-small")
     ap.add_argument("--scale", type=float, default=None)
@@ -133,7 +141,12 @@ def main():
                     help="inject a crash at this step (fault-tolerance demo)")
     ap.add_argument("--max-restarts", type=int, default=3)
     ap.add_argument("--quiet", action="store_true")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = parser().parse_args()
+    compile_cache.enable()
 
     def attempt():
         return run(args)

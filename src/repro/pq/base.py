@@ -12,12 +12,16 @@ the same *protocol* (codes + ``lut_fn``) via their own module.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
+
+# Rows per encode step: a (16384, 16, 256) f32 distance table is 256 MiB.
+ENCODE_BLOCK = 16384
 
 
 class QuantizerModel(NamedTuple):
@@ -43,14 +47,31 @@ class QuantizerModel(NamedTuple):
 
 def rotate_split(model: QuantizerModel, x: jax.Array) -> jax.Array:
     """(N, D) → (N, M, dsub) rotated sub-vectors."""
-    xr = x @ model.r.T
+    xr = jnp.matmul(x, model.r.T, precision=jax.lax.Precision.HIGHEST)
     return xr.reshape(x.shape[0], model.m, model.dsub)
 
 
 def encode(model: QuantizerModel, x: jax.Array, *, backend: str = "auto") -> jax.Array:
-    """(N, D) → (N, M) hard codes (uint8 when K ≤ 256)."""
-    d = kops.pq_pairwise(rotate_split(model, x), model.codebooks, backend=backend)
-    codes = jnp.argmin(d, axis=-1)
+    """(N, D) → (N, M) hard codes (uint8 when K ≤ 256).
+
+    Encodes ``ENCODE_BLOCK`` rows at a time: the (N, M, K) distance table of
+    a whole corpus does not fit a device (16 GB at N=1M, M=16, K=256), the
+    (block, M, K) one does."""
+    return _encode(model, jnp.asarray(x, jnp.float32), backend=backend)
+
+
+@functools.partial(jax.jit, static_argnames=("backend",))
+def _encode(model: QuantizerModel, x: jax.Array, *, backend: str) -> jax.Array:
+    n, d = x.shape
+    block = min(ENCODE_BLOCK, n)
+    xb = jnp.pad(x, ((0, (-n) % block), (0, 0))).reshape(-1, block, d)
+
+    def one(xs):
+        dist = kops.pq_pairwise(rotate_split(model, xs), model.codebooks,
+                                backend=backend)
+        return jnp.argmin(dist, axis=-1)
+
+    codes = jax.lax.map(one, xb).reshape(-1, model.m)[:n]
     return codes.astype(jnp.uint8 if model.k <= 256 else jnp.int32)
 
 
@@ -59,7 +80,8 @@ def decode(model: QuantizerModel, codes: jax.Array) -> jax.Array:
     sub = jnp.take_along_axis(
         model.codebooks[None], codes[:, :, None, None].astype(jnp.int32), axis=2
     )[:, :, 0, :]
-    return sub.reshape(codes.shape[0], -1) @ model.r
+    return jnp.matmul(sub.reshape(codes.shape[0], -1), model.r,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def build_lut(model: QuantizerModel, queries: jax.Array, *,

@@ -622,12 +622,11 @@ def make_adc_dist_fn(codes: jax.Array, *, packed: bool = False,
             return jnp.where(dead, INF, d)
         return dist_fn
 
-    use_fused = backend in ("pallas", "interpret") or (
-        backend == "auto" and jax.default_backend() == "tpu")
+    from repro.kernels import ops
+
+    use_fused = ops._resolve(backend) != "ref"
     if packed:
         if use_fused:
-            from repro.kernels import ops
-
             def dist_fn(qlut, ids):
                 return ops.hop_adc_fs(codes, ids[None], qlut.lut[None],
                                       qlut.scale[None], qlut.bias[None],
@@ -649,22 +648,18 @@ def make_adc_dist_fn(codes: jax.Array, *, packed: bool = False,
     m = codes.shape[1]
     mp = m_prefix if 0 < m_prefix < m else m
     if use_fused:
-        from repro.kernels import ops
-
         def dist_fn(lut, ids):
             return ops.hop_adc(codes, ids[None], lut[None],
                                backend=backend, m_prefix=m_prefix)[0]
         return dist_fn
 
-    if mp < m:
-        def dist_fn(lut, ids):
-            c = codes[ids].astype(jnp.int32)[:, :mp]  # (B, mp)
-            vals = lut[jnp.arange(mp)[None, :], c]    # (B, mp)
-            return jnp.sum(vals, axis=-1)
-        return dist_fn
-
     def dist_fn(lut, ids):
-        c = codes[ids].astype(jnp.int32)              # (B, M)
-        vals = lut[jnp.arange(m)[None, :], c]         # (B, M)
-        return jnp.sum(vals, axis=-1)
+        c = codes[ids].astype(jnp.int32)[:, :mp]      # (B, mp)
+        vals = lut[jnp.arange(mp)[None, :], c]        # (B, mp)
+        # subspace order, as the fused kernel accumulates: the two paths
+        # then agree bit for bit, so a beam never forks on rounding
+        acc = vals[:, 0]
+        for j in range(1, mp):
+            acc = acc + vals[:, j]
+        return acc
     return dist_fn

@@ -56,7 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro._compat import shard_map
 from repro.dist import sharding as shd
 from repro.dist.fault import partial_merge, resolve_quorum
 from repro.graphs.adjacency import Graph
@@ -93,17 +92,19 @@ def _lut_specs(luts):
     return jax.tree.map(lambda a: P(*([None] * jnp.ndim(a))), luts)
 
 
-def _cached_dist_fn(cache: dict, codes_p, luts, m_prefix: int = 0):
+def _cached_dist_fn(cache: dict, codes_p, luts, m_prefix: int = 0,
+                    backend: str = "auto"):
     """Per-(layout, prefix) hop dist fn, cached so beam_search's jit sees
     ONE static callable per layout (u8 vs fs4-packed, decided by the lut
     type) and per partial-LUT prefix (``m_prefix>0`` builds the hop-pruning
-    lower-bound fn, DESIGN.md §11)."""
+    lower-bound fn, DESIGN.md §11). ``backend`` picks the per-hop kernel
+    (beam.make_adc_dist_fn)."""
     packed = _is_packed(luts)
-    fn = cache.get((packed, m_prefix))
+    fn = cache.get((packed, m_prefix, backend))
     if fn is None:
         fn = beam.make_adc_dist_fn(codes_p, packed=packed,
-                                   m_prefix=m_prefix)
-        cache[(packed, m_prefix)] = fn
+                                   m_prefix=m_prefix, backend=backend)
+        cache[(packed, m_prefix, backend)] = fn
     return fn
 
 
@@ -148,6 +149,7 @@ class InMemoryEngine:
     codes: jax.Array                  # (N, M) compact codes
     lut_fn: Callable                  # (Q, D) queries -> (Q, M, K) LUTs
     entry_fn: Optional[Callable] = None  # queries -> (Q,) entries (HNSW descend)
+    backend: str = "auto"             # per-hop kernel (kernels.ops backends)
 
     def __post_init__(self):
         self._codes_p = kops.pad_sentinel_row(self.codes)
@@ -173,10 +175,11 @@ class InMemoryEngine:
         (DESIGN.md §13): traced round / distance-evaluation caps; an
         exhausted query returns best-so-far with ``truncated=True``."""
         luts = self.lut_fn(queries)
-        dist_fn = _cached_dist_fn(self._dist_fns, self._codes_p, luts)
+        dist_fn = _cached_dist_fn(self._dist_fns, self._codes_p, luts,
+                                  backend=self.backend)
         mp, mt = _prune_cfg(luts, prune_eps, m_prefix)
-        lb_fn = (_cached_dist_fn(self._dist_fns, self._codes_p, luts, mp)
-                 if mp else None)
+        lb_fn = (_cached_dist_fn(self._dist_fns, self._codes_p, luts, mp,
+                                 self.backend) if mp else None)
         cal_fn = _cached_scale_fn(self._dist_fns, luts, mp) if mp else None
         seed_cost = jnp.int32(0)
         if entries > 1:
@@ -211,6 +214,7 @@ class HybridEngine:
     vectors: jax.Array                # (N, D) original vectors ("on SSD")
     io_latency_s: float = 100e-6     # per 4 KiB node read (NVMe-class)
     entry_fn: Optional[Callable] = None
+    backend: str = "auto"             # per-hop kernel (kernels.ops backends)
 
     def __post_init__(self):
         self._codes_p = kops.pad_sentinel_row(self.codes)
@@ -242,10 +246,11 @@ class HybridEngine:
         rerank = h if rerank <= 0 else rerank
         k = min(k, rerank)  # cannot return more results than candidates
         luts = self.lut_fn(queries)
-        dist_fn = _cached_dist_fn(self._dist_fns, self._codes_p, luts)
+        dist_fn = _cached_dist_fn(self._dist_fns, self._codes_p, luts,
+                                  backend=self.backend)
         mp, mt = _prune_cfg(luts, prune_eps, m_prefix)
-        lb_fn = (_cached_dist_fn(self._dist_fns, self._codes_p, luts, mp)
-                 if mp else None)
+        lb_fn = (_cached_dist_fn(self._dist_fns, self._codes_p, luts, mp,
+                                 self.backend) if mp else None)
         cal_fn = _cached_scale_fn(self._dist_fns, luts, mp) if mp else None
         seed_cost = jnp.int32(0)
         if entries > 1:
@@ -380,7 +385,7 @@ def sharded_adc_scan(mesh, axes: tuple, codes, luts, *, k: int,
     n_local = codes.shape[0] // shd.axis_size(mesh, axes)
     body = partial(_local_adc_topk, mesh=mesh, axes=axes, n_local=n_local,
                    k=k, n_valid=n_valid)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axes, None), _lut_specs(luts)),
         out_specs=(P(axes, None, None), P(axes, None, None)))(codes, luts)
@@ -394,7 +399,7 @@ def sharded_adc_serve(mesh, axes: tuple, codes, vectors, luts, queries, *,
     n_local = codes.shape[0] // shd.axis_size(mesh, axes)
     body = partial(_local_adc_serve, mesh=mesh, axes=axes, n_local=n_local,
                    k=k, shortlist=min(shortlist, n_local), n_valid=n_valid)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axes, None), P(axes, None), _lut_specs(luts),
                   P(None, None)),
@@ -705,11 +710,15 @@ def sharded_graph_topk(mesh, axes: tuple, neighbors, medoids, codes, luts, *,
         if b is not None:
             ins.append(jnp.asarray(b, jnp.int32))
             specs.append(P())
-    return shard_map(
+    # check_vma=False: the beam's while_loop starts from constants (empty
+    # visited set, unexpanded beam) that the body then mixes with this
+    # shard's blocks; the varying-axes check rejects that carry, and every
+    # output here is per-shard anyway (out_specs split over the mesh axes).
+    return jax.shard_map(
         body, mesh=mesh, in_specs=tuple(specs),
         out_specs=(P(axes, None, None), P(axes, None, None),
                    P(axes, None), P(axes, None), P(axes, None),
-                   P(axes, None)))(*ins)
+                   P(axes, None)), check_vma=False)(*ins)
 
 
 def sharded_graph_serve(mesh, axes: tuple, neighbors, medoids, codes,
@@ -752,11 +761,15 @@ def sharded_graph_serve(mesh, axes: tuple, neighbors, medoids, codes,
         if b is not None:
             ins.append(jnp.asarray(b, jnp.int32))
             specs.append(P())
-    return shard_map(
+    # check_vma=False: the beam's while_loop starts from constants (empty
+    # visited set, unexpanded beam) that the body then mixes with this
+    # shard's blocks; the varying-axes check rejects that carry, and every
+    # output here is per-shard anyway (out_specs split over the mesh axes).
+    return jax.shard_map(
         body, mesh=mesh, in_specs=tuple(specs),
         out_specs=(P(axes, None, None), P(axes, None, None),
                    P(axes, None), P(axes, None), P(axes, None),
-                   P(axes, None)))(*ins)
+                   P(axes, None)), check_vma=False)(*ins)
 
 
 def _stack_rows(x: jax.Array, n_shards: int, n_local: int) -> jax.Array:

@@ -66,9 +66,15 @@ def test_inmemory_recall_and_memory(setup):
     eng = InMemoryEngine(setup["graph"], setup["codes"],
                          _lut_fn(setup["model"]))
     res = eng.search(setup["q"], k=TOPK, h=48)
-    rec = np.mean([len(set(a) & set(b)) / TOPK
-                   for a, b in zip(np.asarray(res.ids), setup["gt"])])
-    assert rec > 0.5
+    recall = lambda ids: np.mean([len(set(a) & set(b)) / TOPK for a, b
+                                  in zip(np.asarray(ids), setup["gt"])])
+    # The bar is the exhaustive-ADC recall of the SAME codes, the ceiling
+    # of any ADC-routed search. An absolute bar (it was 0.5) measured one
+    # PRNG draw of the codebook: 0.5125 on the old threefry stream and
+    # 0.475 on the partitionable one, with beam == ceiling in both.
+    adc = np.asarray(pqbase.adc(setup["model"], setup["codes"], setup["q"]))
+    adc_top = np.argsort(adc, axis=1, kind="stable")[:, :TOPK]
+    assert recall(res.ids) >= recall(adc_top)
     assert eng.memory_bytes() == (setup["codes"].size
                                   + setup["graph"].neighbors.size * 4)
 

@@ -208,12 +208,20 @@ def test_hop_adc_duplicate_and_boundary_ids(rng):
     assert got[0, 0] == got[0, 1] == got[0, 7]
 
 
-def test_default_interpret_off_tpu():
-    """The ONE autodetect switch: interpreter everywhere except real TPU
-    (this container is CPU, so it must say True)."""
+def test_default_interpret_off_tpu(rng):
+    """Interpret mode is never a default: off-TPU only backend="interpret"
+    runs the Pallas interpreter, and backend="pallas" raises instead of
+    quietly interpreting in place of the device kernel."""
     import jax
-    assert ops.default_interpret() == (jax.default_backend() != "tpu")
-    assert ops.default_interpret() is True  # CPU container
+    assert jax.default_backend() != "tpu"  # CPU test host
+    codes = rng.integers(0, 16, (40, 4)).astype(np.uint8)
+    luts = rng.normal(size=(2, 4, 16)).astype(np.float32)
+    ids = rng.integers(0, 40, (2, 8)).astype(np.int32)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        ops.hop_adc(codes, ids, luts, backend="pallas")
+    got = ops.hop_adc(codes, ids, luts, backend="interpret")
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.hop_adc_ref(codes, ids, luts)))
 
 
 def test_hop_gather_consistent_with_adc_scan(rng):
