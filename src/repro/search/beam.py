@@ -10,6 +10,11 @@ we run a *fixed-shape* best-first beam entirely in `jax.lax`:
 * distances come from a pluggable `dist_fn` (ADC LUT gather or exact), so the
   same engine serves PQ-routing and exact-routing.
 
+A round's steps 1, 2 and 4 run under the named scopes `beam.select`,
+`beam.visited` and `beam.merge`: `op_name` metadata in the compiled HLO,
+which ties each device op of a profiler trace to its step. They change no
+operation. Step 3, the distance call, keeps the hop kernel's own name.
+
 **Frontier batching** (`expand=E`, DESIGN.md §9): every `while_loop` round
 expands the E best unexpanded beam entries at once — their E·R neighbor ids
 are deduplicated (against each other and the visited bitset; width-adaptive
@@ -290,30 +295,34 @@ def _single_query(neighbors: jax.Array, entries: jax.Array, qdata,
         step, ids, dists, exp, visited, hops, ndist, tbi, tbd, tbv = state
         # 1. pick the best `e` unexpanded beam entries (e=1 ≡ argmin; top_k
         #    breaks ties toward the lowest index, like argmin)
-        cand = jnp.where(~exp & (dists < INF), dists, INF)
-        neg_sel, sel = jax.lax.top_k(-cand, e)
-        sel_ok = -neg_sel < INF                    # lanes actually selected
-        # non-ok lanes are already expanded or INF slots (exp True by the
-        # merge invariant below), so the unconditional set is a no-op there
-        exp = exp.at[sel].set(True)
-        hops = hops + jnp.sum(sel_ok.astype(jnp.int32))
+        with jax.named_scope("beam.select"):
+            cand = jnp.where(~exp & (dists < INF), dists, INF)
+            neg_sel, sel = jax.lax.top_k(-cand, e)
+            sel_ok = -neg_sel < INF                # lanes actually selected
+            # non-ok lanes are already expanded or INF slots (exp True by
+            # the merge invariant below), so the unconditional set is a
+            # no-op there
+            exp = exp.at[sel].set(True)
+            hops = hops + jnp.sum(sel_ok.astype(jnp.int32))
         # 2. expand the frontier: gather e·R neighbor ids, drop pads,
         #    visited vertices, and (e>1) cross-row duplicates
-        nbr = neighbors[jnp.where(sel_ok, ids[sel], 0)]      # (e, R)
-        flat = nbr.reshape(e * r)
-        valid = (sel_ok[:, None] & (nbr < n)).reshape(e * r)
-        seen = _bit_get(visited, jnp.where(valid, flat, 0)).astype(bool)
-        fresh = valid & ~seen
-        if e > 1:
-            # two frontier rows may share a neighbor; keep the first lane
-            # (then every fresh id is distinct — _scatter_bits suffices)
-            fresh = _first_occurrence(flat, fresh)
-            visited = _scatter_bits(visited, flat, fresh)
-        else:
-            # legacy semantics exactly: fresh keeps theoretical in-row dups
-            # (scored twice, like the pre-PR beam), dedup only inside the
-            # duplicate-safe scatter — bit-identical regression contract
-            visited = _scatter_or(visited, flat, fresh)
+        with jax.named_scope("beam.visited"):
+            nbr = neighbors[jnp.where(sel_ok, ids[sel], 0)]      # (e, R)
+            flat = nbr.reshape(e * r)
+            valid = (sel_ok[:, None] & (nbr < n)).reshape(e * r)
+            seen = _bit_get(visited, jnp.where(valid, flat, 0)).astype(bool)
+            fresh = valid & ~seen
+            if e > 1:
+                # two frontier rows may share a neighbor; keep the first lane
+                # (then every fresh id is distinct — _scatter_bits suffices)
+                fresh = _first_occurrence(flat, fresh)
+                visited = _scatter_bits(visited, flat, fresh)
+            else:
+                # legacy semantics exactly: fresh keeps theoretical in-row
+                # dups (scored twice, like the pre-PR beam), dedup only inside
+                # the duplicate-safe scatter — bit-identical regression
+                # contract
+                visited = _scatter_or(visited, flat, fresh)
         # 3. ONE dist_fn call for the whole e·R frontier (on TPU: one fused
         #    hop-ADC kernel invocation instead of e narrow ones)
         if prune:
@@ -352,13 +361,14 @@ def _single_query(neighbors: jax.Array, entries: jax.Array, qdata,
             # merge invariant, so routing never continues THROUGH them
             nd = jnp.where(is_dead(flat), INF, nd)
         # 4. merge beam ∪ frontier in a single (h + e·R)-wide top-k
-        all_ids = jnp.concatenate([ids, jnp.where(front, flat, n)])
-        all_d = jnp.concatenate([dists, nd])
-        all_e = jnp.concatenate([exp, jnp.zeros((e * r,), bool)])
-        neg, order = jax.lax.top_k(-all_d, h)
-        ids = all_ids[order]
-        dists = -neg
-        exp = all_e[order] | (dists == INF)
+        with jax.named_scope("beam.merge"):
+            all_ids = jnp.concatenate([ids, jnp.where(front, flat, n)])
+            all_d = jnp.concatenate([dists, nd])
+            all_e = jnp.concatenate([exp, jnp.zeros((e * r,), bool)])
+            neg, order = jax.lax.top_k(-all_d, h)
+            ids = all_ids[order]
+            dists = -neg
+            exp = all_e[order] | (dists == INF)
         # 5. trace the ranked candidate beam (paper Def. 6); rounds beyond
         #    trace_len must NOT clobber the last recorded slot
         if do_trace:

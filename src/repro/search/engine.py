@@ -231,6 +231,7 @@ class HybridEngine:
             self._seedix = sseed.build_seed_index(np.asarray(codes))
         return self._seedix
 
+    @partial(jax.profiler.annotate_function, name="rpq.search")
     def search(self, queries: jax.Array, *, k: int = 10, h: int = 32,
                max_steps: int = 512, rerank: int = 0, expand: int = 1,
                entries: int = 1, prune_eps: float = 0.0,
@@ -245,35 +246,42 @@ class HybridEngine:
         skip_rerank = rerank < 0
         rerank = h if rerank <= 0 else rerank
         k = min(k, rerank)  # cannot return more results than candidates
-        luts = self.lut_fn(queries)
-        dist_fn = _cached_dist_fn(self._dist_fns, self._codes_p, luts,
-                                  backend=self.backend)
-        mp, mt = _prune_cfg(luts, prune_eps, m_prefix)
-        lb_fn = (_cached_dist_fn(self._dist_fns, self._codes_p, luts, mp,
-                                 self.backend) if mp else None)
-        cal_fn = _cached_scale_fn(self._dist_fns, luts, mp) if mp else None
-        seed_cost = jnp.int32(0)
-        if entries > 1:
-            ix = self._seed_index(luts)
-            entry = ix.seed_entries(luts, entries)
-            seed_cost = jnp.int32(ix.n_candidates)
-        else:
-            entry = (self.entry_fn(queries) if self.entry_fn is not None
-                     else self.graph.medoid)
-        res = beam.beam_search(self.graph.neighbors, entry, luts,
-                               dist_fn, h=h, max_steps=max_steps,
-                               expand=expand, lb_dist_fn=lb_fn,
-                               m_prefix=mp, m_total=mt,
-                               prune_eps=prune_eps if mp else 0.0,
-                               lb_scale_fn=cal_fn,
-                               max_rounds=max_rounds, max_n_dist=max_n_dist)
-        if skip_rerank:
-            ids, dists = res.ids[:, :k], res.dists[:, :k]
-        else:
-            ids, dists = _exact_rerank(self._vec_p, queries, res.ids,
-                                       rerank, k)
-        return SearchResult(ids, dists, res.hops, res.n_dist + seed_cost,
-                            res.rounds, res.truncated)
+        # host spans on the profiler's clock; the beam's device steps carry
+        # named scopes of their own (search/beam.py)
+        with jax.profiler.TraceAnnotation("rpq.search.lut"):
+            luts = self.lut_fn(queries)
+        with jax.profiler.TraceAnnotation("rpq.search.beam"):
+            dist_fn = _cached_dist_fn(self._dist_fns, self._codes_p, luts,
+                                      backend=self.backend)
+            mp, mt = _prune_cfg(luts, prune_eps, m_prefix)
+            lb_fn = (_cached_dist_fn(self._dist_fns, self._codes_p, luts, mp,
+                                     self.backend) if mp else None)
+            cal_fn = (_cached_scale_fn(self._dist_fns, luts, mp) if mp
+                      else None)
+            seed_cost = jnp.int32(0)
+            if entries > 1:
+                ix = self._seed_index(luts)
+                entry = ix.seed_entries(luts, entries)
+                seed_cost = jnp.int32(ix.n_candidates)
+            else:
+                entry = (self.entry_fn(queries) if self.entry_fn is not None
+                         else self.graph.medoid)
+            res = beam.beam_search(self.graph.neighbors, entry, luts,
+                                   dist_fn, h=h, max_steps=max_steps,
+                                   expand=expand, lb_dist_fn=lb_fn,
+                                   m_prefix=mp, m_total=mt,
+                                   prune_eps=prune_eps if mp else 0.0,
+                                   lb_scale_fn=cal_fn,
+                                   max_rounds=max_rounds,
+                                   max_n_dist=max_n_dist)
+        with jax.profiler.TraceAnnotation("rpq.search.rerank"):
+            if skip_rerank:
+                ids, dists = res.ids[:, :k], res.dists[:, :k]
+            else:
+                ids, dists = _exact_rerank(self._vec_p, queries, res.ids,
+                                           rerank, k)
+            return SearchResult(ids, dists, res.hops, res.n_dist + seed_cost,
+                                res.rounds, res.truncated)
 
     def io_time(self, res: SearchResult, *, expand: int = 1,
                 entries: int = 1, io_fault_p: float = 0.0,
