@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
+from repro.pq.pack import quantize_luts
 
 # Rows per encode step: a (16384, 16, 256) f32 distance table is 256 MiB.
 ENCODE_BLOCK = 16384
@@ -84,6 +85,7 @@ def decode(model: QuantizerModel, codes: jax.Array) -> jax.Array:
                       precision=jax.lax.Precision.HIGHEST)
 
 
+@functools.partial(jax.jit, static_argnames=("quantize",))
 def build_lut(model: QuantizerModel, queries: jax.Array, *,
               quantize: bool = False):
     """(Q, D) → (Q, M, K) per-query ADC lookup tables.
@@ -92,13 +94,16 @@ def build_lut(model: QuantizerModel, queries: jax.Array, *,
     instead — (Q, M, 16) uint8 tables + per-query (scale, bias) — for the
     fast-scan serving layout (requires K ≤ 16; pair with
     ``pack.pack_codes(encode(model, x))``).
+
+    One compiled program per (query shape, ``quantize``): built op by op,
+    the table costs a host dispatch per operation, which is most of a
+    small call. On a TPU v5e its bits equal the op-by-op build's
+    (``tests/test_lut_jit.py``); under an outer ``jit`` or ``grad`` it
+    inlines.
     """
-    qs = rotate_split(model, jnp.atleast_2d(queries))
-    luts = kops.pq_pairwise(qs, model.codebooks, backend="ref")
-    if not quantize:
-        return luts
-    from repro.pq.pack import quantize_luts
-    return quantize_luts(luts)
+    luts = kops.pq_pairwise(rotate_split(model, jnp.atleast_2d(queries)),
+                            model.codebooks, backend="ref")
+    return quantize_luts(luts) if quantize else luts
 
 
 def adc(model: QuantizerModel, codes: jax.Array, queries: jax.Array,

@@ -92,7 +92,12 @@ def quantize_luts(luts: jax.Array) -> QuantizedLUT:
     luts = luts.astype(jnp.float32)
     lo = jnp.min(luts.reshape(q, -1), axis=1)              # (Q,)
     hi = jnp.max(luts.reshape(q, -1), axis=1)
-    scale = jnp.where(hi > lo, (hi - lo) / 255.0, 1.0)
+    # Compiled, a division by a literal becomes a multiplication by its
+    # rounded reciprocal (up to 2 ulps off on a TPU v5e); op by op it is a
+    # division by an operand. Behind the barrier the divisor is an operand
+    # in both, so a compiled scale keeps the op-by-op bits.
+    levels = jax.lax.optimization_barrier(jnp.float32(255.0))
+    scale = jnp.where(hi > lo, (hi - lo) / levels, 1.0)
     qv = jnp.clip(jnp.round((luts - lo[:, None, None]) / scale[:, None, None]),
                   0, 255).astype(jnp.uint8)
     if k < FS_K:
