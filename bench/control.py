@@ -4,6 +4,8 @@ study of a configuration. Not part of a benchmark run.
 
     python3 bench/control.py --workload <cell> --seeds 0 1 2 [--seconds 5]
                              [--study] [--faults one_lane_capped ...]
+    python3 bench/control.py --workload <cell> --fit-keys 1 2 3 ...
+                             [--fit-faults fit_half_batch ...]
 
 It builds the cell's index once, as a run does, and for each seed drives
 the cell's own traffic through the program for ``--seconds`` in that
@@ -13,14 +15,22 @@ seed's order of the queries, and prints one JSON line with:
   program's answers;
 * ``control``: the same numbers for the control, the reference computed
   in bfloat16 (the precision below the configuration's float32) and put
-  in the program's place, on the same sampled queries.
+  in the program's place, on the same sampled queries (for a quantizer
+  trained by ``fit``, on the same first steps too).
 
 ``--faults`` adds one line per fault of ``faults.py`` and per seed of the
-first three: the
-numbers compared for the program with that fault planted under the timed
-path, at the cell's own size. ``--study`` adds, on the first line, the corpus's local intrinsic dimension
-(Levina-Bickel MLE at k=20 over 2000 pool queries) and the hybrid
-recall@10 at each h of ``H_GRID`` (program path, 4096 pool queries).
+first three: the numbers compared for the program with that fault planted
+under the timed path, at the cell's own size. ``--study`` adds, on the
+first line, the corpus's local intrinsic dimension (Levina-Bickel MLE at
+k=20 over 2000 pool queries) and the hybrid recall@10 at each h of
+``H_GRID`` (program path, 4096 pool queries).
+
+``--fit-keys`` reads the fit numbers alone, for a configuration trained by
+``fit``: from the cell's step 0 it runs ``fit``'s first steps with each key
+``fold_in(k_pq, key)`` (a run uses key 1) and stops, and prints one line a
+key with the program's and the control's numbers. ``--fit-faults`` adds
+one line per fault of ``faults.FIT_FAULTS`` and per key of the first
+three.
 """
 
 from __future__ import annotations
@@ -132,7 +142,8 @@ def control_numbers(records, index, pool, conf, traffic, skw, seed):
 
     import reference
 
-    index = dict(index, codes=reference.encode(
+    spy = index["fit"]
+    index = dict(index, fit=None, codes=reference.encode(
         index["base"], index["r"], index["codebooks"],
         conf["quantizer"]["layout"], dtype=jnp.bfloat16))
     pick = harness.sample_calls(records, traffic, seed)
@@ -148,17 +159,73 @@ def control_numbers(records, index, pool, conf, traffic, skw, seed):
         start += n
     nums, _ = harness.check(swapped, index, pool, conf, traffic, skw,
                             seed)
+    if spy is not None:
+        nums.update(fit_numbers(spy, index["base"], conf)[1])
     return nums
+
+
+def fit_numbers(spy, base, conf):
+    """The fit numbers of the program's first steps and of the bfloat16
+    control in their place."""
+    import fit_reference as fr
+
+    program, fit = spy.program(), conf["quantizer"]["fit"]
+    ref = fr.steps(program["params0"], base, spy.feeds, fit)
+    return (fr.numbers(program, ref),
+            fr.control(program["params0"], base, spy.feeds, fit, ref))
+
+
+def fit_readings(args, *, require_chip: bool = True,
+                 overrides: dict | None = None):
+    """One line per key of ``args.fit_keys``, then per fault of
+    ``args.fit_faults`` and key of the first three."""
+    import contextlib
+
+    import jax
+
+    cell, _, conf, traffic, _, _ = harness.find_cell(args.workload,
+                                                     overrides)
+    if require_chip:
+        harness.check_chip(cell["chips"])
+    parts = harness.step0(conf, traffic, {})
+
+    def first_steps(key):
+        spy = harness.FitSteps(stop=True)
+        t0 = time.perf_counter()
+        try:
+            harness.train(parts, conf["quantizer"],
+                          jax.random.fold_in(parts.k_pq, key), spy)
+        except harness.StopFit:
+            pass
+        return spy, time.perf_counter() - t0
+
+    runs = [(key, None) for key in args.fit_keys]
+    runs += [(key, name) for name in args.fit_faults
+             for key in args.fit_keys[:3]]
+    for key, fault in runs:
+        with (faults.planted_fit(fault) if fault
+              else contextlib.nullcontext()):
+            spy, seconds = first_steps(key)
+        program, control = fit_numbers(spy, parts.base, conf)
+        out = {"workload": args.workload, "fit_key": key, "fault": fault,
+               "seconds": seconds, "losses": spy.program()["losses"],
+               "program": program}
+        if fault is None:
+            out["control"] = control
+        yield out
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
-    ap.add_argument("--seeds", type=int, nargs="+", required=True)
+    ap.add_argument("--seeds", type=int, nargs="+", default=[])
     ap.add_argument("--seconds", type=float, default=5.0)
     ap.add_argument("--study", action="store_true")
     ap.add_argument("--faults", nargs="*", default=[],
                     choices=sorted(faults.FAULTS))
+    ap.add_argument("--fit-keys", type=int, nargs="+", default=[])
+    ap.add_argument("--fit-faults", nargs="*", default=[],
+                    choices=sorted(faults.FIT_FAULTS))
     args = ap.parse_args(argv)
     sys.path.insert(0, str(harness.REPO / "src"))
     from repro.launch import compile_cache
@@ -171,7 +238,9 @@ def main(argv=None) -> int:
         return 2
     compile_cache.enable()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    for out in readings(args, args.seeds):
+    lines = (fit_readings(args) if args.fit_keys
+             else readings(args, args.seeds))
+    for out in lines:
         print(json.dumps(out), flush=True)
     return 0
 

@@ -1,6 +1,9 @@
 """Faults planted under the timed path, for the tests and for
-``control.py``: each wraps ``HybridEngine.search`` and breaks what a
-served call returns. A sound run never uses them."""
+``control.py``: each of ``FAULTS`` wraps ``HybridEngine.search`` and breaks
+what a served call returns; each of ``FIT_FAULTS`` breaks the training
+step of a quantizer trained by ``fit``. A sound run never uses them."""
+
+import contextlib
 
 import jax.numpy as jnp
 
@@ -40,3 +43,48 @@ def one_lane_capped(search, rounds: int = 3):
 
 FAULTS = {"altered": altered, "half_left_out": half_left_out,
           "one_lane_capped": one_lane_capped}
+
+
+# Faults of RPQ training, planted in ``fit``'s jitted step: each wraps
+# ``trainer.make_train_step``.
+
+def fit_state_unchanged(make):
+    """Every step returns the parameters and Adam's state it was given."""
+    def broken_make(cfg, tcfg, optimizer):
+        step = make(cfg, tcfg, optimizer)
+
+        def broken(params, opt_state, x, trip, route, key):
+            _, _, report, gnorm = step(params, opt_state, x, trip, route, key)
+            return params, opt_state, report, gnorm
+        return broken
+    return broken_make
+
+
+def fit_half_batch(make):
+    """Half of every step's triplets and routing decisions left out: the
+    loss is the mean over the rest."""
+    def broken_make(cfg, tcfg, optimizer):
+        step = make(cfg, tcfg, optimizer)
+
+        def broken(params, opt_state, x, trip, route, key):
+            half = lambda b: type(b)(*(a[: a.shape[0] // 2] for a in b))  # noqa: E731
+            return step(params, opt_state, x, half(trip), half(route), key)
+        return broken
+    return broken_make
+
+
+FIT_FAULTS = {"fit_state_unchanged": fit_state_unchanged,
+              "fit_half_batch": fit_half_batch}
+
+
+@contextlib.contextmanager
+def planted_fit(name: str):
+    """``fit``'s step broken by the fault ``name`` while the block runs."""
+    from repro.core import trainer
+
+    make = trainer.make_train_step
+    trainer.make_train_step = FIT_FAULTS[name](make)
+    try:
+        yield
+    finally:
+        trainer.make_train_step = make

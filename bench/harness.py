@@ -8,21 +8,33 @@ expand, entries, pool). Per-layer metrics are read by
 Adding a cell, configuration, mix or metric adds files and entries; this
 file does not change.
 
+The configuration's ``quantizer.training`` chooses how the codebooks are
+made. Both start from RPQ's step 0: k-means on ``quantizer.train_rows``
+rows of the base for ``quantizer.kmeans_iters`` iterations, rotation the
+identity. Absent or ``"kmeans"``, that is all; ``"rpq"``, the paper's
+routing-guided training follows, ``core/trainer.fit`` with
+``quantizer.fit`` as its ``TrainConfig``, over the whole base and the
+graph, learning the rotation, its first ``FIT_STEPS_CHECKED`` steps
+recorded for ``fit_reference.py``. Any other value is refused before the
+corpus is built.
+
 A run:
 
 1. set-up, each phase timed on standard error: the corpus and the held-out
    query pool on the device, from the configuration's ``data_seed``; their exact kNN (the
    benchmark's own brute force); the program's Vamana graph; PQ codebooks
-   by k-means (RPQ's step-0 initialisation) on the first half of the base;
-   the code table; the ``HybridEngine`` as ``launch/serve.py`` builds it;
-   two warm-up calls at the cell's shape;
+   by k-means (RPQ's step-0 initialisation) on the first half of the base,
+   then, for ``"rpq"``, ``fit`` (phase ``rpq_fit``); the code table; the
+   ``HybridEngine`` as ``launch/serve.py`` builds it; two warm-up calls at
+   the cell's shape;
 2. the window: a closed loop of ``engine.search`` calls, each with the
    next ``batch`` queries of the pool in an order drawn from ``--seed``,
    until ``--seconds`` have passed;
    with ``--trace 1`` the profiler records its first ``trace_seconds``;
 3. after the window: the device's peak memory; then the served code table
-   is held to the codebooks, and a sample of the answered calls, drawn
-   from the seed, to ``reference.py``.
+   is held to the codebooks, a sample of the answered calls, drawn from
+   the seed, to ``reference.py``, and for ``"rpq"`` the first steps of
+   ``fit`` to ``fit_reference.py``.
 
 The last line of standard output is the result; the numbers compared,
 each beside its limit, are the last lines of standard error.
@@ -31,6 +43,7 @@ each beside its limit, are the last lines of standard error.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import importlib.util
 import json
@@ -47,6 +60,12 @@ import numpy as np
 BENCH = Path(__file__).resolve().parent
 REPO = BENCH.parent
 _T_START = time.perf_counter()
+
+
+# quantizer.training: how a configuration's codebooks are made
+TRAINING = ("kmeans", "rpq")
+# fit's first steps held to fit_reference.py
+FIT_STEPS_CHECKED = 3
 
 
 class NoChip(RuntimeError):
@@ -70,12 +89,22 @@ def load_module(path: Path):
     return mod
 
 
+def merge(target: dict, values: dict) -> None:
+    """``target`` updated by ``values``, nested groups key by key."""
+    for key, value in values.items():
+        if isinstance(value, dict) and isinstance(target.get(key), dict):
+            merge(target[key], value)
+        else:
+            target[key] = value
+
+
 def find_cell(name: str, overrides: dict | None = None):
     """(workload, configuration entry, config file, traffic file, end-to-end
     and per-layer metric entries of this cell) for the cell ``name``.
     ``overrides`` updates parts of the configuration ("shape", "index",
-    "quantizer", "generator", "conf" itself) or the traffic ("traffic"):
-    the tests cut a cell to a size the CPU can hold."""
+    "quantizer", "generator", "conf" itself) or the traffic ("traffic"),
+    nested groups key by key: the tests cut a cell to a size the CPU can
+    hold."""
     bm = load_json(REPO / "BENCHMARK.json")
     cells = {w["name"]: w for w in bm["workloads"]}
     if name not in cells:
@@ -85,8 +114,8 @@ def find_cell(name: str, overrides: dict | None = None):
     conf = load_json(REPO / conf_entry["file"])
     traffic = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
     for part, values in (overrides or {}).items():
-        target = {"conf": conf, "traffic": traffic}.get(part) or conf[part]
-        target.update(values)
+        merge({"conf": conf, "traffic": traffic}.get(part) or conf[part],
+              values)
 
     def reports(metric):
         return name in metric.get("workloads", [name])
@@ -123,23 +152,109 @@ def _timed(phases: dict, label: str, fn, *args, **kw):
     return out
 
 
-def build(conf: dict, traffic: dict, phases: dict):
-    """Corpus, ground truth and the program's index and engine. All of it
-    comes from the configuration's ``data_seed``: every run serves the same
-    deployment, and ``--seed`` changes only the order of the queries and
-    the answers sampled for the check."""
+class StopFit(Exception):
+    """Raised by a ``FitSteps`` that stops training once it has recorded."""
+
+
+class FitSteps:
+    """``fit``'s first ``keep`` steps as its jitted step saw them: the
+    parameters it was first given, each step's feed, key and loss, Adam's
+    state after the first step and the parameters after the last. Installed
+    around ``trainer.make_train_step``, it passes every step through
+    unchanged; with ``stop`` it ends training once it has recorded
+    (``control.py``'s readings)."""
+
+    def __init__(self, keep: int = FIT_STEPS_CHECKED, stop: bool = False):
+        self.keep, self.stop = keep, stop
+        self.feeds, self.losses = [], []
+        self.params0 = self.params = self.first_state = None
+
+    @contextlib.contextmanager
+    def installed(self):
+        from repro.core import trainer
+
+        make = trainer.make_train_step
+
+        def make_recorded(cfg, tcfg, optimizer):
+            step = make(cfg, tcfg, optimizer)
+
+            def recorded(params, opt_state, x, trip, route, key):
+                out = step(params, opt_state, x, trip, route, key)
+                if len(self.feeds) < self.keep:
+                    self.record(params, trip, route, key, out)
+                return out
+            return recorded
+
+        trainer.make_train_step = make_recorded
+        try:
+            yield self
+        finally:
+            trainer.make_train_step = make
+
+    def record(self, params, trip, route, key, out):
+        new, state, report, _ = out
+        if not self.feeds:
+            self.params0, self.first_state = params, state
+        self.feeds.append({"trip": trip._asdict(),
+                           "route": {"q": route.q, "cand": route.cand,
+                                     "valid": route.valid},
+                           "key": key})
+        self.losses.append(report.total)
+        self.params = new
+        if self.stop and len(self.feeds) == self.keep:
+            raise StopFit
+
+    def program(self) -> dict:
+        """What ``fit_reference.numbers`` compares: the first gradient is
+        Adam's first moment after one step over ``1 - b1``."""
+        import fit_reference as fr
+
+        if len(self.feeds) < self.keep:
+            raise RuntimeError(f"fit ran {len(self.feeds)} recorded steps, "
+                               f"{self.keep} are checked")
+        first_moment = self.first_state.inner[0]
+        leaves = lambda p: {k: np.asarray(getattr(p, k)) for k in fr.LEAVES}  # noqa: E731
+        return {"params0": leaves(self.params0),
+                "params": leaves(self.params),
+                "losses": [float(v) for v in self.losses],
+                "grad": {k: v / (1 - fr.ADAM_B1)
+                         for k, v in leaves(first_moment).items()}}
+
+
+def log_fit(state, step0) -> None:
+    """``fit``'s first and last logged losses, and how far it moved the
+    quantizer from step 0, on standard error (the sums tell two runs'
+    quantizers apart)."""
+    for rec in (state.history[0], state.history[-1]):
+        log(f"rpq_fit step {rec['step']}: total {rec['total']:.6f} "
+            f"routing {rec['routing']:.6f} neighborhood "
+            f"{rec['neighborhood']:.6f} alpha {rec['alpha']:.6f} "
+            f"wall {rec['wall']:.3f} s")
+    for name in ("theta", "codebooks"):
+        new, old = (np.asarray(getattr(p, name)) for p in (state.params,
+                                                             step0))
+        log(f"rpq_fit {name}: largest change {np.abs(new - old).max():.6g}, "
+            f"sum {np.sum(new, dtype=np.float64):.17g}")
+
+
+def step0(conf: dict, traffic: dict, phases: dict) -> SimpleNamespace:
+    """Corpus, ground truth, the program's graph and RPQ's step 0. All of
+    it comes from the configuration's ``data_seed``: every run serves the
+    same deployment, and ``--seed`` changes only the order of the queries
+    and the answers sampled for the check."""
     import jax
     from repro.core import RPQConfig
-    from repro.core.trainer import init_rpq, to_model
+    from repro.core.trainer import init_rpq
     from repro.graphs.vamana import build_vamana
-    from repro.pq import base as pqbase
-    from repro.pq import pack
-    from repro.search.engine import HybridEngine
 
     import reference
 
     shape, gen, idx, qz = (conf["shape"], conf["generator"], conf["index"],
                            conf["quantizer"])
+    training = qz.get("training", "kmeans")
+    if training not in TRAINING:
+        raise ValueError(f"quantizer.training {training!r} is not one of "
+                         f"{TRAINING}")
     key = seed_key(gen["data_seed"])
     k_data, k_graph, k_pq = jax.random.split(key, 3)
     make = load_module(BENCH / "data" / f"{gen['module']}.py").make
@@ -151,23 +266,57 @@ def build(conf: dict, traffic: dict, phases: dict):
                    r=idx["R"], l=idx["L"], alpha=idx["alpha"],
                    passes=idx["passes"])
     cfg = RPQConfig(dim=shape["dim"], m=qz["M"], k=qz["K"],
-                    learn_rotation=False)
-    train = base[: qz["train_rows"]]
-    params = _timed(phases, "codebooks", init_rpq, k_pq, cfg, train,
-                    kmeans_iters=qz["kmeans_iters"])
-    model = to_model(cfg, params)
+                    learn_rotation=training == "rpq")
+    params = _timed(phases, "codebooks", init_rpq, k_pq, cfg,
+                    base[: qz["train_rows"]], kmeans_iters=qz["kmeans_iters"])
+    return SimpleNamespace(base=base, pool=pool, gt=gt, graph=graph, cfg=cfg,
+                           params=params, k_pq=k_pq, training=training)
+
+
+def train(parts, qz: dict, key, spy: FitSteps):
+    """``fit`` from step 0 over the whole base (its samplers index the
+    graph's rows), its first steps recorded by ``spy``."""
+    import jax
+    from repro.core.trainer import TrainConfig, fit
+
+    with spy.installed():
+        state = fit(key, parts.cfg, TrainConfig(**qz["fit"]), parts.base,
+                    parts.graph, params=parts.params, verbose=False)
+    # the state is no pytree: its parameters are waited for
+    jax.block_until_ready(state.params)
+    return state
+
+
+def build(conf: dict, traffic: dict, phases: dict):
+    """The program's index and engine, and what the check needs of it."""
+    import jax
+    from repro.core.trainer import to_model
+    from repro.pq import base as pqbase
+    from repro.pq import pack
+    from repro.search.engine import HybridEngine
+
+    qz = conf["quantizer"]
+    parts = step0(conf, traffic, phases)
+    params, spy = parts.params, None
+    if parts.training == "rpq":
+        spy = FitSteps()
+        state = _timed(phases, "rpq_fit", train, parts, qz,
+                       jax.random.fold_in(parts.k_pq, 1), spy)
+        log_fit(state, parts.params)
+        params = state.params
+    model = to_model(parts.cfg, params)
+    base = parts.base
     codes = _timed(phases, "encode", pqbase.encode, model, base)
-    fs4 = qz["layout"] == "fs4"
-    if fs4:
+    if qz["layout"] == "fs4":
         codes = pack.pack_codes(codes)
         lut_fn = lambda q: pqbase.build_lut(model, q, quantize=True)  # noqa: E731
     else:
         lut_fn = lambda q: pqbase.build_lut(model, q)  # noqa: E731
-    engine = HybridEngine(graph, codes, lut_fn, vectors=base)
-    index = {"neighbors": graph.neighbors, "entry": graph.medoid,
+    engine = HybridEngine(parts.graph, codes, lut_fn, vectors=base)
+    index = {"neighbors": parts.graph.neighbors, "entry": parts.graph.medoid,
              "r": model.r, "codebooks": model.codebooks, "codes": codes,
-             "base": base}
-    return engine, index, np.asarray(pool), np.asarray(gt)
+             "base": base, "fit": spy}
+    return engine, index, np.asarray(parts.pool), np.asarray(parts.gt)
 
 
 def search_kwargs(conf: dict, traffic: dict) -> dict:
@@ -291,7 +440,24 @@ def check(records, index, pool, conf, traffic, skw, seed):
     true_d = np.asarray(reference.returned_dists(
         jnp.asarray(q), jnp.asarray(ids), index["base"]))
     nums = reference.compare(ids, dists, ref_ids, ref_d, true_d)
-    return {"code_rows_wrong": wrong, **nums}, len(rows)
+    return {"code_rows_wrong": wrong, **nums,
+            **check_fit(index, conf)}, len(rows)
+
+
+def check_fit(index, conf) -> dict:
+    """For a quantizer trained by ``fit``: its first steps against
+    ``fit_reference.steps`` from the same step 0 and feeds."""
+    import fit_reference
+
+    spy = index.get("fit")
+    if spy is None:
+        return {}
+    program = spy.program()
+    ref = fit_reference.steps(program["params0"], index["base"], spy.feeds,
+                              conf["quantizer"]["fit"])
+    log(f"fit's first steps: losses {program['losses']}, reference "
+        f"{[s.loss for s in ref]}")
+    return fit_reference.numbers(program, ref)
 
 
 def reduce_trace(trace_dir: str, records, traced: int):
@@ -317,8 +483,10 @@ def reduce_trace(trace_dir: str, records, traced: int):
                                                             t0, t1))
 
 
-def layer_context(records, trace, conf, traffic, peaks, kernels):
-    """What a per-layer reader may read (``metrics/<metric>.py``)."""
+def layer_context(records, trace, conf, traffic, peaks, kernels, phases):
+    """What a per-layer reader may read (``metrics/<metric>.py``): the
+    traced calls, the trace, the chip's peaks, the set-up phases, the
+    configuration, and each kernel's events, costs and roofline share."""
     import profile_reduce as pr
 
     qz, idx = conf["quantizer"], conf["index"]
@@ -333,8 +501,22 @@ def layer_context(records, trace, conf, traffic, peaks, kernels):
             events=events, bytes=cost.bytes_per_call(**shape),
             ops=cost.ops_per_call(**shape))
 
+    def roofline(name):
+        """Share of its HBM roofline that kernel ``name`` reaches: (calls
+        x least time of one call) over the calls' summed device time. The
+        least time is the algorithmic bytes of a call over peak HBM
+        bandwidth: the hop kernels' lookup-adds are far below any compute
+        peak. No event of the kernel in the trace: nothing to read."""
+        k = kernel(name)
+        if not k.events:
+            return None
+        least = k.bytes / peaks["hbm_bytes_per_s"]
+        spent = sum(d for _, _, d in k.events) / 1e9
+        return 100.0 * len(k.events) * least / spent
+
     return SimpleNamespace(records=records, trace=trace, peaks=peaks,
-                           kernel=kernel)
+                           phases=phases, conf=conf, kernel=kernel,
+                           roofline=roofline)
 
 
 def run(args, *, require_chip: bool = True, overrides: dict | None = None):
@@ -413,7 +595,7 @@ def run(args, *, require_chip: bool = True, overrides: dict | None = None):
     metrics = {}
     if args.trace:
         ctx = layer_context(records[:traced], trace, conf, traffic, peaks,
-                            kernels)
+                            kernels, phases)
         for m in layer:
             reader = load_module(BENCH / "metrics" / f"{m['name']}.py")
             value = reader.read(ctx)

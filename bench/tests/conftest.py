@@ -12,8 +12,12 @@ for p in (BENCH, BENCH.parent / "src"):
 import pytest  # noqa: E402
 
 # A cell cut to what a CPU test can hold: every code path of a run, tiny.
+# Only a configuration trained by fit reads the quantizer's "fit".
 TINY = {"shape": {"n_base": 3000},
-        "quantizer": {"train_rows": 1500, "kmeans_iters": 4},
+        "quantizer": {"train_rows": 1500, "kmeans_iters": 4,
+                      "fit": {"steps": 4, "triplet_batch": 64,
+                              "routing_batch": 64, "routing_pool_queries": 32,
+                              "log_every": 1}},
         "index": {"R": 16, "L": 24},
         "traffic": {"pool": 300, "check_queries": 64, "trace_seconds": 1},
         "conf": {"operating_h": 24}}

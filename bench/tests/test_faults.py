@@ -10,7 +10,9 @@ from repro.search import engine as eng
 
 @pytest.mark.parametrize("fault", sorted(faults.FAULTS))
 @pytest.mark.parametrize("cell", ["sift128-hybrid-u8.batch1024",
-                                  "deep96-hybrid-fs4.online16"])
+                                  "deep96-hybrid-fs4.online16",
+                                  "deep96-hybrid-fs4.batch1024",
+                                  "sift128-rpq-u8.batch1024"])
 def test_fault_is_not_correct(cell, fault, tiny, no_disk_cache, monkeypatch):
     monkeypatch.setattr(eng.HybridEngine, "search",
                         faults.FAULTS[fault](eng.HybridEngine.search))
@@ -19,6 +21,21 @@ def test_fault_is_not_correct(cell, fault, tiny, no_disk_cache, monkeypatch):
     result, checks = harness.run(args, require_chip=False, overrides=tiny)
     assert result["correct"] is False
     assert any(c["value"] > c["limit"] for c in checks.values())
+
+
+@pytest.mark.parametrize("fault", sorted(faults.FIT_FAULTS))
+def test_fit_fault_is_not_correct(fault, tiny, no_disk_cache):
+    """A quantizer whose training step is broken comes out not correct,
+    by one of the fit numbers."""
+    args = harness.parser().parse_args(
+        ["--workload", "sift128-rpq-u8.batch1024", "--seed", "5",
+         "--seconds", "1", "--trace", "0"])
+    with faults.planted_fit(fault):
+        result, checks = harness.run(args, require_chip=False,
+                                     overrides=tiny)
+    assert result["correct"] is False
+    assert any(c["value"] > c["limit"] for k, c in checks.items()
+               if k.startswith("fit_"))
 
 
 @pytest.mark.parametrize("layout", ["u8", "fs4"])

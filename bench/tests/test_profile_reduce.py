@@ -22,9 +22,11 @@ def test_clip_to_window():
 
 
 def test_kernel_time_by_name():
+    """Whole instruction names: the u8 and fs4 kernels' events stay apart."""
     k = pr.matching(EVENTS + [(HOP_FS, 800, 10)], ("hop_adc",))
     assert len(k) == 2 and sum(d for _, _, d in k) == 300
-    assert pr.matching([(HOP_FS, 800, 10)], ("hop_adc_fs",))
+    assert pr.matching(EVENTS + [(HOP_FS, 800, 10)], ("hop_adc_fs",)) == [
+        (HOP_FS, 800, 10)]
 
 
 def test_op_names_are_short():
