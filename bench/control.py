@@ -18,12 +18,13 @@ seed's order of the queries, and prints one JSON line with:
   in the program's place, on the same sampled queries (for a quantizer
   trained by ``fit``, on the same first steps too).
 
-``--faults`` adds one line per fault of ``faults.py`` and per seed of the
-first three: the numbers compared for the program with that fault planted
-under the timed path, at the cell's own size. ``--study`` adds, on the
-first line, the corpus's local intrinsic dimension (Levina-Bickel MLE at
-k=20 over 2000 pool queries) and the hybrid recall@10 at each h of
-``H_GRID`` (program path, 4096 pool queries).
+``--faults`` adds one line per fault of ``faults.py`` (``SHARD_FAULTS``
+for a sharded cell alone) and per seed of the first three: the numbers
+compared for the program with that fault planted under the timed path,
+at the cell's own size. ``--study`` adds, on the first line, the
+corpus's local intrinsic dimension (Levina-Bickel MLE at k=20 over 2000
+pool queries) and the recall@10 at each h of ``H_GRID`` (program path,
+4096 pool queries).
 
 ``--fit-keys`` reads the fit numbers alone, for a configuration trained by
 ``fit``: from the cell's step 0 it runs ``fit``'s first steps with each key
@@ -45,7 +46,8 @@ import numpy as np
 import faults
 import harness
 
-H_GRID = (32, 48, 64, 96, 128, 192, 256)
+H_GRID = (16, 24, 32, 48, 64, 96, 128, 192, 256)
+SERVING_FAULTS = {**faults.FAULTS, **faults.SHARD_FAULTS}
 
 
 def lid_mle(base, queries, k: int = 20) -> dict:
@@ -124,7 +126,7 @@ def readings(args, seeds, *, require_chip: bool = True,
         study = {}
     for name in getattr(args, "faults", None) or []:
         search = type(engine).search
-        plant = faults.FAULTS[name](search)
+        plant = SERVING_FAULTS[name](search)
         engine.search = lambda queries, **kw: plant(engine, queries, **kw)
         try:
             for seed in seeds[:3]:
@@ -143,11 +145,15 @@ def control_numbers(records, index, pool, conf, traffic, skw, seed):
     import reference
 
     spy = index["fit"]
-    index = dict(index, fit=None, codes=reference.encode(
-        index["base"], index["r"], index["codebooks"],
-        conf["quantizer"]["layout"], dtype=jnp.bfloat16))
+    codes = reference.encode(index["base"], index["r"], index["codebooks"],
+                             conf["quantizer"]["layout"], dtype=jnp.bfloat16)
+    sharded = "medoids" in index
+    if sharded:   # laid out as the served table is
+        codes = reference.stack_rows(codes, index["codes"].shape[0])
+    index = dict(index, fit=None, codes=codes)
     pick = harness.sample_calls(records, traffic, seed)
-    ids, d = reference.answer(
+    answer = reference.answer_sharded if sharded else reference.answer
+    ids, d = answer(
         [pool[records[i].rows] for i in pick], index,
         layout=conf["quantizer"]["layout"], h=skw["h"], k=skw["k"],
         max_steps=skw["max_steps"], dtype=jnp.bfloat16)
@@ -222,7 +228,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=5.0)
     ap.add_argument("--study", action="store_true")
     ap.add_argument("--faults", nargs="*", default=[],
-                    choices=sorted(faults.FAULTS))
+                    choices=sorted(SERVING_FAULTS))
     ap.add_argument("--fit-keys", type=int, nargs="+", default=[])
     ap.add_argument("--fit-faults", nargs="*", default=[],
                     choices=sorted(faults.FIT_FAULTS))

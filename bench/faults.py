@@ -1,7 +1,9 @@
 """Faults planted under the timed path, for the tests and for
-``control.py``: each of ``FAULTS`` wraps ``HybridEngine.search`` and breaks
-what a served call returns; each of ``FIT_FAULTS`` breaks the training
-step of a quantizer trained by ``fit``. A sound run never uses them."""
+``control.py``: each of ``FAULTS`` wraps an engine's ``search``
+(``HybridEngine`` or ``ShardedGraphEngine``) and breaks what a served call
+returns; each of ``SHARD_FAULTS`` does so for ``ShardedGraphEngine``
+alone; each of ``FIT_FAULTS`` breaks the training step of a quantizer
+trained by ``fit``. A sound run never uses them."""
 
 import contextlib
 
@@ -13,7 +15,7 @@ def altered(search):
     query's nearest id replaced by another row, its distance kept."""
     def broken(self, queries, **kw):
         res = search(self, queries, **kw)
-        n = self.vectors.shape[0]
+        n = self.graph.n
         ids = res.ids.at[0, 0].set((res.ids[0, 0] + n // 2) % n)
         return res._replace(ids=ids)
     return broken
@@ -43,6 +45,19 @@ def one_lane_capped(search, rounds: int = 3):
 
 FAULTS = {"altered": altered, "half_left_out": half_left_out,
           "one_lane_capped": one_lane_capped}
+
+
+def one_shard_dropped(search):
+    """The last shard marked dead in every call, and the answer not marked
+    degraded: its rows drop out of every merge unannounced."""
+    def broken(self, queries, **kw):
+        alive = [True] * (self.n_shards - 1) + [False]
+        res = search(self, queries, alive=alive, **kw)
+        return res._replace(degraded=False)
+    return broken
+
+
+SHARD_FAULTS = {"one_shard_dropped": one_shard_dropped}
 
 
 # Faults of RPQ training, planted in ``fit``'s jitted step: each wraps

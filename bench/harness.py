@@ -18,23 +18,34 @@ graph, learning the rotation, its first ``FIT_STEPS_CHECKED`` steps
 recorded for ``fit_reference.py``. Any other value is refused before the
 corpus is built.
 
+The configuration's ``index.engine`` chooses the program's engine. Absent
+or ``"hybrid"``: one Vamana graph over the whole base and a
+``HybridEngine`` on one chip. ``"sharded_graph"``: the base split into
+``index.shards`` contiguous row ranges, one Vamana graph each
+(``graphs/partition.build_partitioned_vamana``), served by a
+``ShardedGraphEngine`` whose mesh is the first ``index.shards`` devices
+on one axis; ``index.shards`` must equal the cell's ``chips``, and
+``fit`` is not run over it. Any other value, or ``"sharded_graph"`` with
+``"rpq"``, is refused before the corpus is built.
+
 A run:
 
 1. set-up, each phase timed on standard error: the corpus and the held-out
    query pool on the device, from the configuration's ``data_seed``; their exact kNN (the
-   benchmark's own brute force); the program's Vamana graph; PQ codebooks
-   by k-means (RPQ's step-0 initialisation) on the first half of the base,
-   then, for ``"rpq"``, ``fit`` (phase ``rpq_fit``); the code table; the
-   ``HybridEngine`` as ``launch/serve.py`` builds it; two warm-up calls at
-   the cell's shape;
+   benchmark's own brute force); the program's Vamana graph (one per
+   shard for ``"sharded_graph"``); PQ codebooks by k-means (RPQ's step-0
+   initialisation) on the first half of the base, then, for ``"rpq"``,
+   ``fit`` (phase ``rpq_fit``); the code table; the engine as
+   ``launch/serve.py`` builds it; two warm-up calls at the cell's shape;
 2. the window: a closed loop of ``engine.search`` calls, each with the
    next ``batch`` queries of the pool in an order drawn from ``--seed``,
    until ``--seconds`` have passed;
    with ``--trace 1`` the profiler records its first ``trace_seconds``;
-3. after the window: the device's peak memory; then the served code table
+3. after the window: the devices' peak memory; then the served code table
    is held to the codebooks, a sample of the answered calls, drawn from
-   the seed, to ``reference.py``, and for ``"rpq"`` the first steps of
-   ``fit`` to ``fit_reference.py``.
+   the seed, to ``reference.py``, for ``"rpq"`` the first steps of
+   ``fit`` to ``fit_reference.py``, and for ``"sharded_graph"`` the
+   placement of the engine's resident arrays to the mesh.
 
 The last line of standard output is the result; the numbers compared,
 each beside its limit, are the last lines of standard error.
@@ -64,6 +75,8 @@ _T_START = time.perf_counter()
 
 # quantizer.training: how a configuration's codebooks are made
 TRAINING = ("kmeans", "rpq")
+# index.engine: the program's engine a configuration is served by
+ENGINES = ("hybrid", "sharded_graph")
 # fit's first steps held to fit_reference.py
 FIT_STEPS_CHECKED = 3
 
@@ -116,6 +129,12 @@ def find_cell(name: str, overrides: dict | None = None):
     for part, values in (overrides or {}).items():
         merge({"conf": conf, "traffic": traffic}.get(part) or conf[part],
               values)
+    idx = conf["index"]
+    if (idx.get("engine") == "sharded_graph"
+            and idx.get("shards") != cell["chips"]):
+        raise ValueError(f"index.shards {idx.get('shards')!r} of "
+                         f"{conf['name']} is not the cell's chips "
+                         f"{cell['chips']}")
 
     def reports(metric):
         return name in metric.get("workloads", [name])
@@ -245,6 +264,7 @@ def step0(conf: dict, traffic: dict, phases: dict) -> SimpleNamespace:
     import jax
     from repro.core import RPQConfig
     from repro.core.trainer import init_rpq
+    from repro.graphs.partition import build_partitioned_vamana
     from repro.graphs.vamana import build_vamana
 
     import reference
@@ -255,6 +275,12 @@ def step0(conf: dict, traffic: dict, phases: dict) -> SimpleNamespace:
     if training not in TRAINING:
         raise ValueError(f"quantizer.training {training!r} is not one of "
                          f"{TRAINING}")
+    engine = idx.get("engine", "hybrid")
+    if engine not in ENGINES:
+        raise ValueError(f"index.engine {engine!r} is not one of {ENGINES}")
+    if engine == "sharded_graph" and training == "rpq":
+        raise ValueError("quantizer.training 'rpq' samples over one graph: "
+                         "not with index.engine 'sharded_graph'")
     key = seed_key(gen["data_seed"])
     k_data, k_graph, k_pq = jax.random.split(key, 3)
     make = load_module(BENCH / "data" / f"{gen['module']}.py").make
@@ -262,15 +288,20 @@ def step0(conf: dict, traffic: dict, phases: dict) -> SimpleNamespace:
                         traffic["pool"], gen)
     gt, _ = _timed(phases, "ground_truth", reference.exact_knn, base, pool,
                    traffic["k"])
-    graph = _timed(phases, "graph", build_vamana, k_graph, base,
-                   r=idx["R"], l=idx["L"], alpha=idx["alpha"],
-                   passes=idx["passes"])
+    vamana = dict(r=idx["R"], l=idx["L"], alpha=idx["alpha"],
+                  passes=idx["passes"])
+    if engine == "hybrid":
+        graph = _timed(phases, "graph", build_vamana, k_graph, base, **vamana)
+    else:
+        graph = _timed(phases, "graph", build_partitioned_vamana, k_graph,
+                       base, idx["shards"], **vamana)
     cfg = RPQConfig(dim=shape["dim"], m=qz["M"], k=qz["K"],
                     learn_rotation=training == "rpq")
     params = _timed(phases, "codebooks", init_rpq, k_pq, cfg,
                     base[: qz["train_rows"]], kmeans_iters=qz["kmeans_iters"])
     return SimpleNamespace(base=base, pool=pool, gt=gt, graph=graph, cfg=cfg,
-                           params=params, k_pq=k_pq, training=training)
+                           params=params, k_pq=k_pq, training=training,
+                           engine=engine)
 
 
 def train(parts, qz: dict, key, spy: FitSteps):
@@ -312,11 +343,43 @@ def build(conf: dict, traffic: dict, phases: dict):
         lut_fn = lambda q: pqbase.build_lut(model, q, quantize=True)  # noqa: E731
     else:
         lut_fn = lambda q: pqbase.build_lut(model, q)  # noqa: E731
-    engine = HybridEngine(parts.graph, codes, lut_fn, vectors=base)
-    index = {"neighbors": parts.graph.neighbors, "entry": parts.graph.medoid,
-             "r": model.r, "codebooks": model.codebooks, "codes": codes,
-             "base": base, "fit": spy}
+    if parts.engine == "hybrid":
+        engine = HybridEngine(parts.graph, codes, lut_fn, vectors=base)
+        index = {"neighbors": parts.graph.neighbors,
+                 "entry": parts.graph.medoid, "r": model.r,
+                 "codebooks": model.codebooks, "codes": codes, "base": base,
+                 "fit": spy}
+    else:
+        engine = sharded_engine(parts.graph, codes, lut_fn, base,
+                                conf["index"]["shards"])
+        index = sharded_index(engine, parts.graph, model, base)
     return engine, index, np.asarray(parts.pool), np.asarray(parts.gt)
+
+
+def sharded_engine(graph, codes, lut_fn, base, shards: int):
+    """A ``ShardedGraphEngine`` over the first ``shards`` devices, on one
+    mesh axis."""
+    import jax
+    from jax.sharding import Mesh
+    from repro.search.engine import ShardedGraphEngine
+
+    mesh = Mesh(np.array(jax.devices()[:shards]), ("data",))
+    return ShardedGraphEngine(graph, codes, lut_fn, vectors=base, mesh=mesh)
+
+
+def sharded_index(engine, graph, model, base) -> dict:
+    """What the check needs of a sharded build: the per-shard graphs, the
+    served code table as the engine holds it (shard-stacked, on the
+    host), the base, and the engine's resident arrays with the mesh's
+    devices, whose placement ``shards_misplaced`` reads. Nothing of the
+    program's merge."""
+    return {"neighbors": graph.neighbors, "medoids": graph.medoids,
+            "r": model.r, "codebooks": model.codebooks,
+            "codes": np.asarray(engine.codes), "base": base, "fit": None,
+            # the engine keeps its adjacency under a private name only
+            "resident": {"codes": engine.codes, "neighbors": engine._nbrs_s,
+                         "vectors": engine.vectors},
+            "devices": list(engine.mesh.devices.flat)}
 
 
 def search_kwargs(conf: dict, traffic: dict) -> dict:
@@ -375,7 +438,8 @@ def closed_loop(engine, pool, order, traffic, skw, seconds, trace_dir=None,
                 te = time.perf_counter()
             records.append(SimpleNamespace(rows=rows, t0=ts, t1=te, ids=ids,
                                            dists=dists, n_dist=n_dist,
-                                           rounds=rounds))
+                                           rounds=rounds,
+                                           degraded=bool(res.degraded)))
             i += 1
             if trace_dir and traced is None and (
                     te - t_begin >= traffic["trace_seconds"]
@@ -402,9 +466,11 @@ def recall(records, gt, k: int) -> float:
 
 
 def memory_peak() -> int:
+    """The peak on the fullest device; each device's on standard error."""
     import jax
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in jax.local_devices()]
+    log(f"peak bytes in use by device: {peaks}; reported {max(peaks)}")
     return int(max(peaks))
 
 
@@ -420,28 +486,45 @@ def sample_calls(records, traffic, seed) -> np.ndarray:
 def check(records, index, pool, conf, traffic, skw, seed):
     """The numbers that decide ``correct``: the served code table held to
     the codebooks, and a sample of the answered calls, drawn from the
-    seed, against ``reference.answer``."""
+    seed, against ``reference.answer`` (``answer_sharded`` for a sharded
+    build, whose resident arrays are also held to the mesh)."""
     import jax.numpy as jnp
 
     import reference
 
-    wrong = reference.code_rows_wrong(index["base"], index["r"],
-                                      index["codebooks"], index["codes"],
-                                      conf["quantizer"]["layout"])
+    sharded = "medoids" in index
+    n = index["base"].shape[0]
+    table = (reference.global_rows(index["codes"], n) if sharded
+             else index["codes"])
+    wrong = 1.0 if table is None else reference.code_rows_wrong(
+        index["base"], index["r"], index["codebooks"], table,
+        conf["quantizer"]["layout"])
     pick = sample_calls(records, traffic, seed)
     rows = np.concatenate([records[i].rows for i in pick])
     ids = np.concatenate([records[i].ids for i in pick])
     dists = np.concatenate([records[i].dists for i in pick])
     q = pool[rows]
-    ref_ids, ref_d = reference.answer(
+    answer = reference.answer_sharded if sharded else reference.answer
+    ref_ids, ref_d = answer(
         [pool[records[i].rows] for i in pick], index,
         layout=conf["quantizer"]["layout"], h=skw["h"], k=skw["k"],
         max_steps=skw["max_steps"])
     true_d = np.asarray(reference.returned_dists(
         jnp.asarray(q), jnp.asarray(ids), index["base"]))
     nums = reference.compare(ids, dists, ref_ids, ref_d, true_d)
+    if sharded:
+        nums["shards_misplaced"] = reference.shards_misplaced(
+            index["resident"], index["devices"])
     return {"code_rows_wrong": wrong, **nums,
             **check_fit(index, conf)}, len(rows)
+
+
+def failed_queries(records, k: int, n_base: int) -> int:
+    """Queries whose top-k holds a sentinel (an id below 0, as the sharded
+    merge pads, or at or above ``n_base``), or whose call came back
+    degraded (a shard left out of the merge)."""
+    return int(sum((((r.ids[:, :k] < 0) | (r.ids[:, :k] >= n_base)).any(1)
+                    | r.degraded).sum() for r in records))
 
 
 def check_fit(index, conf) -> dict:
@@ -474,11 +557,12 @@ def reduce_trace(trace_dir: str, records, traced: int):
     t0 = spans[0][1]
     t1 = max(s + d for _, s, d in spans[: 2 * traced])
     per_dev = {p: pr.clip(ev, t0, t1) for p, ev in devices.items()}
-    busy = [pr.busy_ns(ev) for ev in per_dev.values()]
+    busy = [pr.busy_ns(per_dev[p]) for p in sorted(per_dev)]
     first = per_dev[sorted(per_dev)[0]]
     return SimpleNamespace(
         events=first, window_s=(t1 - t0) / 1e9,
         busy_s=float(np.mean(busy)) / 1e9,
+        device_busy_s=[b / 1e9 for b in busy],
         device_ops=pr.top_ops(first), idle_gaps=pr.idle_gaps(first, host,
                                                             t0, t1))
 
@@ -589,8 +673,7 @@ def run(args, *, require_chip: bool = True, overrides: dict | None = None):
         f"{time.perf_counter() - t0:.3f} s; {nums}")
     limits = {k: v for k, v in conf["limits"].items() if k in nums}
     correct = all(nums[k] <= v for k, v in limits.items())
-    failed = int(sum((r.ids[:, : skw["k"]] >= conf["shape"]["n_base"]).any(1)
-                     .sum() for r in records))
+    failed = failed_queries(records, skw["k"], conf["shape"]["n_base"])
 
     metrics = {}
     if args.trace:

@@ -32,6 +32,15 @@ the control):
 3. rerank: the exact squared L2 distance to each of the ``h`` candidates,
    and the ``k`` nearest of them.
 
+A sharded deployment (``answer_sharded``) is the same search run on each
+shard alone: shard s of S owns the base rows
+``[s * ceil(n/S), min((s + 1) * ceil(n/S), n))``, and its own graph (the
+program's, in local ids, taken as given) is searched from its own entry
+vertex over that shard's code and base rows; the local ids are mapped to
+global ids and the S shards' top-k lists are merged by exact float32
+distance, ties to the smaller global id. ``shards_misplaced`` holds the
+program's resident arrays to that layout on the devices.
+
 ``exact_knn`` is the ground truth for recall: blocked brute force at
 ``Precision.HIGHEST`` (a TPU's default float32 matmul runs bfloat16
 passes, which reorder neighbours closer than 0.4% of the distance).
@@ -331,6 +340,82 @@ def answer(calls, index: dict, *, layout: str, h: int, k: int,
             ids.append(np.asarray(i))
             dists.append(np.asarray(d))
     return np.concatenate(ids), np.concatenate(dists)
+
+
+def shard_bounds(n: int, shards: int) -> list:
+    """Global [lo, hi) rows of each shard: ceil(n / shards) rows a shard,
+    the last ones shorter."""
+    n_local = -(-n // shards)
+    return [(s * n_local, min((s + 1) * n_local, n)) for s in range(shards)]
+
+
+def global_rows(stacked, n: int):
+    """A shard-stacked (S, ceil(n/S), ...) table in global row order, the
+    padding rows dropped; None for a table of another shape."""
+    stacked = np.asarray(stacked)
+    if stacked.ndim < 2 or stacked.shape[1] != -(-n // stacked.shape[0]):
+        return None
+    return stacked.reshape((-1,) + stacked.shape[2:])[:n]
+
+
+def stack_rows(table, shards: int):
+    """(n, ...) rows in global order → (S, ceil(n/S), ...) shard-stacked,
+    zero rows padding the last shard: the inverse of ``global_rows``."""
+    table = np.asarray(table)
+    n_local = -(-table.shape[0] // shards)
+    pad = shards * n_local - table.shape[0]
+    table = np.concatenate([table, np.zeros((pad,) + table.shape[1:],
+                                            table.dtype)])
+    return table.reshape((shards, n_local) + table.shape[1:])
+
+
+def answer_sharded(calls, index: dict, *, layout: str, h: int, k: int,
+                   max_steps: int, dtype=jnp.float32, block: int = 1024):
+    """The reference's answers of a sharded deployment: ``answer`` on each
+    shard alone (its graph ``index["neighbors"][s]`` in local ids, entry
+    ``index["medoids"][s]``, its rows of the shard-stacked code table and
+    of the base), local ids mapped to global ids, then the S top-k lists
+    merged by exact distance, ties to the smaller id. A sentinel or a
+    padding row answers (-1, +inf)."""
+    neighbors = np.asarray(index["neighbors"])
+    codes, medoids = np.asarray(index["codes"]), np.asarray(index["medoids"])
+    base = np.asarray(index["base"])
+    shards, n_local = neighbors.shape[:2]
+    ids, dists = [], []
+    for s, (lo, hi) in enumerate(shard_bounds(base.shape[0], shards)):
+        rows = np.zeros((n_local, base.shape[1]), base.dtype)
+        rows[: hi - lo] = base[lo:hi]
+        shard = {"neighbors": neighbors[s], "entry": medoids[s],
+                 "codes": codes[s], "base": rows, "r": index["r"],
+                 "codebooks": index["codebooks"]}
+        i, d = answer(calls, shard, layout=layout, h=h, k=k,
+                      max_steps=max_steps, dtype=dtype, block=block)
+        real = i < hi - lo
+        ids.append(np.where(real, i + lo, -1))
+        dists.append(np.where(real, d, np.inf).astype(np.float32))
+    ids, dists = np.concatenate(ids, axis=1), np.concatenate(dists, axis=1)
+    order = np.lexsort((ids, dists), axis=1)[:, :k]
+    return (np.take_along_axis(ids, order, axis=1),
+            np.take_along_axis(dists, order, axis=1))
+
+
+def shards_misplaced(arrays: dict, devices) -> int:
+    """How many (array, device) pairs break the sharded layout: each of
+    the S ``devices`` holds exactly one (1, n_local, ...) block of each
+    (S, n_local, ...) array, block s on device s. An index kept whole on
+    one device, or copied to every device, breaks it."""
+    bad = 0
+    for a in arrays.values():
+        held = {}
+        for piece in a.addressable_shards:
+            held.setdefault(piece.device, []).append(piece)
+        for s, dev in enumerate(devices):
+            mine = held.get(dev, [])
+            ok = (len(mine) == 1 and a.shape[0] == len(devices)
+                  and mine[0].data.shape == (1,) + a.shape[1:]
+                  and range(a.shape[0])[mine[0].index[0]] == range(s, s + 1))
+            bad += not ok
+    return bad
 
 
 def compare(ids, dists, ref_ids, ref_dists, true_dists):

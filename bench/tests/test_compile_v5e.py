@@ -1,11 +1,12 @@
 """Compile rehearsal for a described TPU v5e at the cells' own shapes.
 
 No chip is needed: the TPU compiler compiles for a v5e that is described,
-not attached. The hop kernels at the cells' shapes, and the whole vmapped
-beam of each cell with its kernel inside, must compile and hold a
-``tpu_custom_call``. The topology is described inside a fixture, never at
-import (only one process at a time may load the TPU library), and the
-persistent compilation cache is off around the compiles.
+not attached. The hop kernels at the cells' shapes, the whole vmapped
+beam of each cell with its kernel inside, and the sharded cell's scatter
+over a 2x2 mesh must compile and hold a ``tpu_custom_call``. The topology
+is described inside a fixture, never at import (only one process at a
+time may load the TPU library), and the persistent compilation cache is
+off around the compiles.
 """
 
 import importlib
@@ -14,13 +15,14 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 N = 100_000
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -35,9 +37,14 @@ def one_chip():
         except Exception as e:  # noqa: BLE001 - no TPU compiler here
             jax.config.update("jax_enable_compilation_cache", was_on)
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
         jax.config.update("jax_enable_compilation_cache", was_on)
         cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(one_chip, shape, dtype):
@@ -92,4 +99,32 @@ def test_cell_beam_compiles(one_chip, monkeypatch, layout, q, m, k):
     fn = lambda nb, lut: beam.beam_search(nb, jnp.int32(0), lut, dist_fn,  # noqa: E731
                                           h=64, max_steps=512)
     text = _compiled_text(fn, _spec(one_chip, (N, 64), jnp.int32), luts)
+    assert "tpu_custom_call" in text
+
+
+def test_sharded_scatter_compiles_on_four_chips(topo, monkeypatch):
+    """The sharded cell's scatter as ``ShardedGraphEngine`` runs it: each
+    chip's beam over its 50k rows with the compiled hop kernel, then the
+    exact rerank against its own rows, over a 2x2 mesh on one axis; the
+    LUTs and queries replicated."""
+    from repro.kernels import ops
+    from repro.search import engine
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    s, n_local, h = 4, 50_000, 32
+
+    def spec(shape, dtype, *part):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*part)))
+
+    fn = lambda nb, md, cd, vc, lu, q: engine.sharded_graph_serve(  # noqa: E731
+        mesh, ("data",), nb, md, cd, vc, lu, q, k=10, h=h, shortlist=h,
+        n_valid=s * n_local)
+    text = _compiled_text(
+        fn, spec((s, n_local, 64), jnp.int32, "data", None, None),
+        spec((s,), jnp.int32, "data"),
+        spec((s, n_local, 16), jnp.uint8, "data", None, None),
+        spec((s, n_local, 128), jnp.float32, "data", None, None),
+        spec((1024, 16, 256), jnp.float32), spec((1024, 128), jnp.float32))
     assert "tpu_custom_call" in text
